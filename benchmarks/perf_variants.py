@@ -781,8 +781,7 @@ def run_aggregation(dataset: str = "com-amazon", algo: str = "louvain",
     from repro.core.engine import SweepEngine
     from repro.core.louvain import LouvainConfig, leiden, louvain
     from repro.graph import datasets
-    from repro.kernels.common import (bin_table_bytes, pick_bin_width,
-                                      resolve_bin_impl)
+    from repro.kernels.common import pick_bin_width, resolve_bin_impl
 
     lg = datasets.load(dataset)
     g = lg.graph
@@ -842,7 +841,7 @@ def run_aggregation(dataset: str = "com-amazon", algo: str = "louvain",
             cur = aggregation.shrink_graph(cur, *cap)
             com = com[:cap[0]]
         width = pick_bin_width(cur.n_max, cur.m_max)
-        impl = resolve_bin_impl("auto", bin_table_bytes(cur.n_max, width))
+        impl = resolve_bin_impl("auto")
 
         results = {k: jax.block_until_ready(f(cur, com))
                    for k, f in arms.items()}  # warm/compile
@@ -933,8 +932,9 @@ def run_aggregation(dataset: str = "com-amazon", algo: str = "louvain",
     return out
 
 
-def _egonet_standins(n_graphs: int, seed: int):
-    """Ego-net-scale SBM stand-ins for the serving workload.
+def egonet_edges(n_graphs: int, seed: int):
+    """Ego-net-scale SBM stand-ins for the serving workload, as undirected
+    ``(u, v, n)`` edge lists.
 
     com-dblp has average degree ~6, so real ego-nets are TINY (tens of
     vertices, a few hundred directed edges) — exactly the regime where
@@ -943,18 +943,25 @@ def _egonet_standins(n_graphs: int, seed: int):
     (asserted in tests/test_batch.py)."""
     import numpy as np
 
-    from repro.graph.builders import from_numpy_edges
     from repro.graph.generators import sbm
 
     sizes = (25, 35, 45)    # smoke and full differ in count, not scale
     rng = np.random.default_rng(seed)
-    graphs = []
+    out = []
     for i in range(n_graphs):
         n = int(rng.choice(sizes))
         k = int(rng.integers(3, 6))
         u, v, _w, _t = sbm(n, k, p_in=0.35, p_out=0.03, seed=seed + 7919 * i)
-        graphs.append(from_numpy_edges(u, v, n=n))
-    return graphs
+        out.append((u, v, n))
+    return out
+
+
+def _egonet_standins(n_graphs: int, seed: int):
+    """``egonet_edges`` as graphs."""
+    from repro.graph.builders import from_numpy_edges
+
+    return [from_numpy_edges(u, v, n=n)
+            for u, v, n in egonet_edges(n_graphs, seed)]
 
 
 def run_batch_serve(dataset: str = "com-dblp", algo: str = "both",

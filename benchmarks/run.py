@@ -180,50 +180,82 @@ def bench_fig2_fig3_louvain(repeat=2):
 # ------------------------------------------------------------------ fig 4
 
 
-_SCALING_SNIPPET = r"""
-import os, json, time, sys
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-import numpy as np, jax
-from jax.sharding import Mesh
-from repro.graph import datasets
-from repro.core.distributed import distributed_louvain
-lg = datasets.load("com-livejournal")
-nd = int(sys.argv[1])
-mesh = Mesh(np.array(jax.devices()[:nd]).reshape(nd), ("data",))
-# fused pipeline (default): one dispatch for the whole level loop
-res = distributed_louvain(lg.graph, mesh)      # warm compile + run
-t0 = time.time()
-res = distributed_louvain(lg.graph, mesh)
-total = time.time() - t0
-# per-level driver: the paper's local-moving/aggregation phase breakdown
-distributed_louvain(lg.graph, mesh, pipeline_fused=False)   # warm
-t0 = time.time()
-res_pl = distributed_louvain(lg.graph, mesh, pipeline_fused=False)
-total_pl = time.time() - t0
-print(json.dumps({"devices": nd, "total_s": total,
-                  "per_level_total_s": total_pl,
-                  "pipeline_speedup": total_pl / total,
-                  "phases": dict(res_pl.timer.totals),
-                  "sweeps_per_level": res.sweeps_per_level,
-                  "n_comm_per_level": res.n_comm_per_level,
-                  "modularity": float(res.modularity)}))
-"""
+def _mesh(nd: int):
+    import jax
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:nd]).reshape(nd), ("data",))
 
 
-def bench_fig4_strong_scaling(device_counts=(1, 2, 4, 8)):
+def _fig4_record(nd: int) -> dict:
+    """One fig4 cell: com-livejournal on an ``nd``-device mesh."""
+    from repro.core.distributed import distributed_louvain
+    from repro.graph import datasets
+
+    lg = datasets.load("com-livejournal")
+    mesh = _mesh(nd)
+    # fused pipeline (default): one dispatch for the whole level loop
+    res = distributed_louvain(lg.graph, mesh)      # warm compile + run
+    t0 = time.perf_counter()
+    res = distributed_louvain(lg.graph, mesh)
+    total = time.perf_counter() - t0
+    # per-level driver: the paper's local-moving/aggregation phase breakdown
+    distributed_louvain(lg.graph, mesh, pipeline_fused=False)   # warm
+    t0 = time.perf_counter()
+    res_pl = distributed_louvain(lg.graph, mesh, pipeline_fused=False)
+    total_pl = time.perf_counter() - t0
+    return {"devices": nd, "total_s": total,
+            "per_level_total_s": total_pl,
+            "pipeline_speedup": total_pl / total,
+            "phases": dict(res_pl.timer.totals),
+            "sweeps_per_level": res.sweeps_per_level,
+            "n_comm_per_level": res.n_comm_per_level,
+            "modularity": float(res.modularity)}
+
+
+def _mesh_records(tag: str, record: str, device_counts, *args) -> list:
+    """``record(nd, *args)`` for each device count.
+
+    Under ``JAX_PLATFORMS=cpu`` each count runs in a child process given
+    that many forced host devices (the device count locks when JAX first
+    starts).  Anywhere else the meshes are built in this process from the
+    real devices, so no child ever contends for a chip; counts above the
+    devices present are dropped with a note.  A failed cell raises, which
+    fails the benchmark."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        import jax
+
+        have = len(jax.devices())
+        dropped = [nd for nd in device_counts if nd > have]
+        if dropped:
+            print(f"[{tag}] only {have} device(s): skipping {dropped}")
+        fn = globals()[record]
+        return [fn(nd, *args) for nd in device_counts if nd <= have]
     rows = []
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     for nd in device_counts:
-        p = subprocess.run([sys.executable, "-c", _SCALING_SNIPPET, str(nd)],
+        env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+                   XLA_FLAGS=f"--xla_force_host_platform_device_count={nd}")
+        code = ("import json, sys; from benchmarks import run; "
+                "from repro.utils.compile_cache import use_compile_cache; "
+                "use_compile_cache(); "
+                f"print(json.dumps(run.{record}(*json.loads(sys.argv[1]))))")
+        p = subprocess.run([sys.executable, "-c", code,
+                            json.dumps([nd, *args])],
                            capture_output=True, text=True, env=env, cwd=REPO,
                            timeout=1800)
         if p.returncode != 0:
-            print(f"[fig4] devices={nd} FAILED\n{p.stderr[-800:]}")
-            continue
-        rec = json.loads(p.stdout.strip().splitlines()[-1])
-        rows.append(rec)
+            raise RuntimeError(
+                f"[{tag}] devices={nd} failed (rc={p.returncode}):\n"
+                f"{p.stderr[-800:]}")
+        rows.append(json.loads(p.stdout.strip().splitlines()[-1]))
+    return rows
+
+
+def bench_fig4_strong_scaling(device_counts=(1, 2, 4, 8)):
+    rows = _mesh_records("fig4", "_fig4_record", device_counts)
+    for rec in rows:
         ph = rec.get("phases", {})
-        print(f"[fig4] devices={nd:3d} total={rec['total_s']:6.2f}s "
+        print(f"[fig4] devices={rec['devices']:3d} total={rec['total_s']:6.2f}s "
               f"Q={rec['modularity']:.4f} phases={ {k: round(v,2) for k,v in ph.items()} }")
     if rows:
         base = rows[0]["total_s"]
@@ -236,64 +268,61 @@ def bench_fig4_strong_scaling(device_counts=(1, 2, 4, 8)):
 # ------------------------------------------------------------------ dist scale
 
 
-_DIST_SCALE_SNIPPET = r"""
-import os, json, time, sys
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-import numpy as np, jax
-from jax.sharding import Mesh
-from repro.graph import datasets
-from repro.core.louvain import LouvainConfig, louvain
-from repro.core.distributed import distributed_louvain
-nd = int(sys.argv[1])
-lg = datasets.load(sys.argv[2])
-g = lg.graph
-mesh = Mesh(np.array(jax.devices()[:nd]).reshape(nd), ("data",))
-rs = distributed_louvain(g, mesh, coarsening="shard_local")     # warm compile
-t0 = time.time()
-rs = distributed_louvain(g, mesh, coarsening="shard_local")
-t_shard = time.time() - t0
-rr = distributed_louvain(g, mesh, coarsening="replicated")      # warm compile
-t0 = time.time()
-rr = distributed_louvain(g, mesh, coarsening="replicated")
-t_repl = time.time() - t0
-rl = louvain(g, LouvainConfig())
-# bit-identical: shard-local == replicated oracle == local fused driver
-assert np.array_equal(rs.labels, rr.labels)
-assert np.array_equal(rs.labels, np.asarray(rl.labels))
-assert rs.modularity == rr.modularity == float(rl.modularity)
-assert rs.sweeps_per_level == rr.sweeps_per_level == rl.sweeps_per_level
-assert rs.n_comm_per_level == rr.n_comm_per_level == rl.n_comm_per_level
-assert not rs.run_report.degradations
-# comm-bytes counter: the per-level collective payload must stay
-# O(boundary + communities), never the replicated all_gather's O(m)
-cs = rs.comm_stats
-rep_bytes = cs["bytes_per_level_model"]["replicated"]
-assert all(b < rep_bytes for b in cs["actual_bytes_per_level"])
-# fig4-style phase split from the per-level reference driver
-distributed_louvain(g, mesh, pipeline_fused=False)              # warm
-t0 = time.time()
-rp = distributed_louvain(g, mesh, pipeline_fused=False)
-t_pl = time.time() - t0
-m_pad, h_cap = cs["m_pad"], cs["halo_cap"]
-print(json.dumps({
-    "devices": nd, "graph": sys.argv[2],
-    "V": int(lg.n), "E": int(lg.m_undirected),
-    "shard_local_total_s": t_shard, "replicated_total_s": t_repl,
-    "per_level_total_s": t_pl,
-    "phases_fused": dict(rs.timer.totals),
-    "phases_per_level": dict(rp.timer.totals),
-    "modularity": rs.modularity, "levels": rs.levels,
-    "m_pad": m_pad, "halo_cap": h_cap,
-    "agg_rows_per_device_shard_local": m_pad + nd * h_cap,
-    "agg_rows_per_device_replicated": nd * m_pad,
-    "comm_bytes_model": cs["bytes_per_level_model"],
-    "actual_bytes_per_level": cs["actual_bytes_per_level"],
-    "gathered_groups_per_level": cs["gathered_groups_per_level"],
-    "halo_labels": cs["halo_labels"],
-    "partition_stats": rs.partition_stats,
-    "bit_identical": True,
-}))
-"""
+def _dist_scale_record(nd: int, dataset: str) -> dict:
+    """One dist_scale cell on an ``nd``-device mesh, with its bit-identity
+    and comm-bytes checks."""
+    from repro.core.distributed import distributed_louvain
+    from repro.core.louvain import LouvainConfig, louvain
+    from repro.graph import datasets
+
+    lg = datasets.load(dataset)
+    g = lg.graph
+    mesh = _mesh(nd)
+    rs = distributed_louvain(g, mesh, coarsening="shard_local")     # warm compile
+    t0 = time.perf_counter()
+    rs = distributed_louvain(g, mesh, coarsening="shard_local")
+    t_shard = time.perf_counter() - t0
+    rr = distributed_louvain(g, mesh, coarsening="replicated")      # warm compile
+    t0 = time.perf_counter()
+    rr = distributed_louvain(g, mesh, coarsening="replicated")
+    t_repl = time.perf_counter() - t0
+    rl = louvain(g, LouvainConfig())
+    # bit-identical: shard-local == replicated oracle == local fused driver
+    assert np.array_equal(rs.labels, rr.labels)
+    assert np.array_equal(rs.labels, np.asarray(rl.labels))
+    assert rs.modularity == rr.modularity == float(rl.modularity)
+    assert rs.sweeps_per_level == rr.sweeps_per_level == rl.sweeps_per_level
+    assert rs.n_comm_per_level == rr.n_comm_per_level == rl.n_comm_per_level
+    assert not rs.run_report.degradations
+    # comm-bytes counter: the per-level collective payload must stay
+    # O(boundary + communities), never the replicated all_gather's O(m)
+    cs = rs.comm_stats
+    rep_bytes = cs["bytes_per_level_model"]["replicated"]
+    assert all(b < rep_bytes for b in cs["actual_bytes_per_level"])
+    # fig4-style phase split from the per-level reference driver
+    distributed_louvain(g, mesh, pipeline_fused=False)              # warm
+    t0 = time.perf_counter()
+    rp = distributed_louvain(g, mesh, pipeline_fused=False)
+    t_pl = time.perf_counter() - t0
+    m_pad, h_cap = cs["m_pad"], cs["halo_cap"]
+    return {
+        "devices": nd, "graph": dataset,
+        "V": int(lg.n), "E": int(lg.m_undirected),
+        "shard_local_total_s": t_shard, "replicated_total_s": t_repl,
+        "per_level_total_s": t_pl,
+        "phases_fused": dict(rs.timer.totals),
+        "phases_per_level": dict(rp.timer.totals),
+        "modularity": rs.modularity, "levels": rs.levels,
+        "m_pad": m_pad, "halo_cap": h_cap,
+        "agg_rows_per_device_shard_local": m_pad + nd * h_cap,
+        "agg_rows_per_device_replicated": nd * m_pad,
+        "comm_bytes_model": cs["bytes_per_level_model"],
+        "actual_bytes_per_level": cs["actual_bytes_per_level"],
+        "gathered_groups_per_level": cs["gathered_groups_per_level"],
+        "halo_labels": cs["halo_labels"],
+        "partition_stats": rs.partition_stats,
+        "bit_identical": True,
+    }
 
 
 def bench_dist_scale(device_counts=(1, 2, 4, 8), dataset="com-dblp"):
@@ -302,18 +331,10 @@ def bench_dist_scale(device_counts=(1, 2, 4, 8), dataset="com-dblp"):
     vs local fused driver), the measured collective payload vs the
     replicated all_gather baseline, and the per-device aggregation-work
     trend that carries the weak-scaling claim on emulated meshes."""
-    rows = []
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
-    for nd in device_counts:
-        p = subprocess.run([sys.executable, "-c", _DIST_SCALE_SNIPPET,
-                            str(nd), dataset],
-                           capture_output=True, text=True, env=env, cwd=REPO,
-                           timeout=1800)
-        if p.returncode != 0:
-            print(f"[dist_scale] devices={nd} FAILED\n{p.stderr[-800:]}")
-            continue
-        rec = json.loads(p.stdout.strip().splitlines()[-1])
-        rows.append(rec)
+    rows = _mesh_records("dist_scale", "_dist_scale_record", device_counts,
+                         dataset)
+    for rec in rows:
+        nd = rec["devices"]
         model = rec["comm_bytes_model"]
         print(f"[dist_scale] devices={nd:2d} "
               f"shard_local={rec['shard_local_total_s']:6.2f}s "
@@ -621,6 +642,9 @@ def main(argv=None) -> int:
     """
     import traceback
 
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     names = (argv or sys.argv[1:]) or list(ALL)
     unknown = [n for n in names if n not in ALL]
     if unknown:

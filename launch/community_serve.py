@@ -451,7 +451,8 @@ def _smoke(n_requests: int, deadline_ms: Optional[float],
     small graphs (two size classes → two signature buckets) with deadlines,
     flush, and REQUIRE a typed response for every accepted request.  Armed
     fault points (``REPRO_FAULTS``) perturb the run; the contract is
-    "never hang, never drop" — errors are acceptable, silence is not."""
+    "never hang, never drop" — errors are acceptable, silence is not.  With
+    no fault armed every response must be ``ok``."""
     import json as _json
 
     rng = np.random.default_rng(seed)
@@ -488,6 +489,11 @@ def _smoke(n_requests: int, deadline_ms: Optional[float],
     if missing:
         print(f"FATAL: {len(missing)} accepted request(s) got no response")
         return 1
+    if not faultinject.active() and ok < len(responses):
+        # with no fault armed every response must be an answer
+        print(f"FATAL: {len(responses) - ok} response(s) not ok with no "
+              "fault armed")
+        return 1
     return 0
 
 
@@ -504,4 +510,7 @@ if __name__ == "__main__":
     a = ap.parse_args()
     if not a.smoke:
         ap.error("this entrypoint only implements --smoke")
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(_smoke(a.requests, a.deadline_ms, a.seed))

@@ -66,8 +66,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import aggregation
 from repro.core.engine import (EngineSpec, make_distributed_phase,
-                               make_distributed_step, phase_loop,
-                               shard_map_compat)
+                               make_distributed_step, phase_loop)
 from repro.core.modularity import modularity
 from repro.graph.partition import (EdgePartition, build_halo,
                                    partition_edges_by_dst, partition_quality)
@@ -517,10 +516,10 @@ def make_distributed_pipeline(mesh: Mesh, n: int, m_pad: int,
         return (final, n_final, levels, q, sweeps_hist, ncomm_hist,
                 mod_hist, dn_hist, pg_hist, overflow)
 
-    sharded = shard_map_compat(
-        worker, mesh,
+    sharded = jax.shard_map(
+        worker, mesh=mesh,
         in_specs=(espec,) * 4 + (rspec,) * 2,
-        out_specs=(rspec,) * 10,
+        out_specs=(rspec,) * 10, check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -629,6 +628,9 @@ def distributed_louvain(
                 (table_bytes + gct * EDGE_WIRE_BYTES) if gct >= 0
                 else model["replicated"] for gct in gathered],
             "halo_labels": int(pq.total_ghosts),
+            # the devices that held an edge shard during this run
+            "shard_devices": sorted(s.device.id
+                                    for s in src.addressable_shards),
         }
         return DistLouvainResult(
             labels=np.asarray(final),

@@ -275,13 +275,15 @@ def _evaluate_ell_traced(spec: EngineSpec, g: Graph, tile, labels, active,
     scatter of ``_grid_propose`` reduces to a ``where``.  Vertices wider
     than the tile fall back to the tables tail evaluator over the FULL edge
     list, gated by ``lax.cond`` so hub-free levels skip the per-sweep sort
-    entirely.  Tables are forced resident: coarse tables are small by
-    construction and streaming needs host-side window metadata."""
+    entirely.  Tables are never streamed: coarse tables are small by
+    construction and streaming needs host-side window metadata (with no
+    windows ``auto`` resolves to resident, or to the gathered layout on a
+    TPU)."""
     n = g.n_max
     rows, nbr, w_t, is_tail = tile
     eval_bucket, eval_tail = _ell_evaluators(
         spec, g, labels, it, seed, use_pallas=(spec.backend == "pallas"),
-        table_mode="resident")
+        table_mode="auto")
     best, good = eval_bucket(rows, nbr, w_t, None)
     row_prop = (rows < n) & active & good
     proposal = jnp.where(row_prop, best, -1)
@@ -436,7 +438,8 @@ class SweepEngine:
             from repro.graph import ell as ell_mod
 
             if ell is None:
-                ell = ell_mod.build_device_ell(g)
+                ell = ell_mod.build_device_ell(
+                    g, ell_mod.bucket_widths(spec.backend))
             elif isinstance(ell, ell_mod.EllGraph):
                 ell = ell_mod.to_device(g, ell)
             self.ell = ell
@@ -497,17 +500,6 @@ class SweepEngine:
 
 
 # ----------------------------------------------------------------- distributed
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (check_vma vs check_rep spelling)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
 
 
 def make_distributed_step(spec: EngineSpec, axes, n: int, src, dst, w, emask,
@@ -604,9 +596,9 @@ def make_distributed_phase(mesh, n: int, spec: EngineSpec):
         return phase_loop(step, labels, active, it0, seed,
                           spec.max_sweeps, spec.threshold)
 
-    sharded = shard_map_compat(
-        worker, mesh,
+    sharded = jax.shard_map(
+        worker, mesh=mesh,
         in_specs=(espec,) * 4 + (rspec,) * 7,
-        out_specs=(rspec,) * 5,
+        out_specs=(rspec,) * 5, check_vma=False,
     )
     return jax.jit(sharded)

@@ -57,7 +57,8 @@ from repro.core.engine import EngineSpec, SweepEngine, device_phase
 from repro.core.modularity import modularity
 from repro.core.progcache import program_cache
 from repro.graph.structure import Graph
-from repro.kernels.common import accum_needs_promotion, pick_ell_width
+from repro.kernels.common import (accum_needs_promotion, on_tpu,
+                                  pick_ell_width)
 from repro.utils import faultinject, resilience, telemetry
 from repro.utils.errors import (CapacityError, CommunityDetectionError,
                                 KernelError, NumericError, RunReport)
@@ -73,6 +74,18 @@ ENGINE_FAULTS = ("oscillation", "vmem_starve")
 # input by the kernel≡ell≡segment parity contracts, so descending can only
 # trade speed, never results.
 BACKEND_DESCENT = {"pallas": "ell", "ell": "segment"}
+
+
+def backend_descent(backend: str) -> Optional[str]:
+    """The ladder's next backend after a failure of ``backend``, or None.
+
+    Never on a TPU: there a backend failure is a fault of the kernels for
+    that chip (a Pallas lowering or compile error), and descending would
+    hand back an XLA answer in place of the device path that was asked
+    for.  The failure surfaces as a ``KernelError``."""
+    if on_tpu():
+        return None
+    return BACKEND_DESCENT.get(backend)
 
 # Sweep-counter stride per level and the refinement phase's offset within a
 # level: level L's local-moving phase hashes tie noise / Luby gates from
@@ -721,7 +734,8 @@ def _louvain_pipeline(g: Graph, cfg: LouvainConfig,
         from repro.graph import ell as ell_mod
 
         with timer.phase("ell_build"):
-            ell_k = ell_mod.build_device_ell(g)
+            ell_k = ell_mod.build_device_ell(
+                g, ell_mod.bucket_widths(cfg.backend))
 
     with timer.phase("pipeline"):
         while True:
@@ -861,7 +875,8 @@ def louvain(g: Graph, cfg: LouvainConfig = LouvainConfig(),
       * capacity bust (``CapacityError``) → ONE retry on the
         single-capacity ``capacity_schedule="none"`` program;
       * non-taxonomy backend failure → descend ``pallas → ell → segment``
-        (each step bit-identical on clean input by the parity contracts);
+        (each step bit-identical on clean input by the parity contracts),
+        except on a TPU, where it raises (``backend_descent``);
       * typed taxonomy errors (numeric, validation, …) propagate — they
         mean the ANSWER is unsafe, so no amount of retrying helps;
 
@@ -901,7 +916,7 @@ def louvain(g: Graph, cfg: LouvainConfig = LouvainConfig(),
             err.report = report
             raise
         except Exception as err:  # noqa: BLE001 — the backend-descent rung
-            nxt = BACKEND_DESCENT.get(cfg_try.backend)
+            nxt = backend_descent(cfg_try.backend)
             if nxt is None:
                 raise KernelError(
                     f"backend {cfg_try.backend!r} failed with no descent "

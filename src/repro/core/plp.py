@@ -105,9 +105,9 @@ def plp(g: Graph, cfg: PLPConfig = PLPConfig(), ell_graph=None) -> PLPResult:
 
     Hardened like ``core.louvain.louvain``: non-taxonomy backend failures
     descend the ``pallas → ell → segment`` ladder (bit-identical on clean
-    input), iteration-budget exhaustion is flagged as a watchdog warning,
+    input; never on a TPU), iteration-budget exhaustion is flagged as a watchdog warning,
     and everything attempted lands in ``result.run_report``."""
-    from repro.core.louvain import BACKEND_DESCENT
+    from repro.core.louvain import backend_descent
 
     report = RunReport(faults=sorted(faultinject.active()))
     if g.n_max == 0:
@@ -124,7 +124,7 @@ def plp(g: Graph, cfg: PLPConfig = PLPConfig(), ell_graph=None) -> PLPResult:
             err.report = report
             raise
         except Exception as err:  # noqa: BLE001 — the backend-descent rung
-            nxt = BACKEND_DESCENT.get(cfg_try.backend)
+            nxt = backend_descent(cfg_try.backend)
             if nxt is None:
                 raise KernelError(
                     f"backend {cfg_try.backend!r} failed with no descent "

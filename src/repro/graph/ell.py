@@ -28,6 +28,11 @@ import numpy as np
 from repro.graph.structure import Graph
 
 BUCKET_WIDTHS = (16, 64, 256, 1024)
+# On a TPU the pallas backend's scoring kernel holds an (R_blk, W, W)
+# pairwise tensor that outgrows VMEM past W=256 even at one sublane of rows
+# (8·1024² f32 = 32 MiB), so its layout stops there and wider rows join the
+# hub tail.  Off the chip the interpret-mode kernels take every width.
+TPU_PALLAS_BUCKET_WIDTHS = (16, 64, 256)
 ROW_PAD = 8  # sublane alignment for (rows, W) tiles
 CHUNK_ELEMS = 1 << 15  # target neighbor slots per stacked chunk (DESIGN.md §Kernels)
 
@@ -376,6 +381,15 @@ def to_device(g: Graph, e: EllGraph, rows_per_chunk: Optional[int] = None,
         n_max=n,
         has_tail=bool(e.tail_vertices.size),
     )
+
+
+def bucket_widths(backend: str) -> Tuple[int, ...]:
+    """Level-0 bucket widths for the ``ell`` or ``pallas`` backend."""
+    from repro.kernels.common import on_tpu
+
+    if backend == "pallas" and on_tpu():
+        return TPU_PALLAS_BUCKET_WIDTHS
+    return BUCKET_WIDTHS
 
 
 def build_device_ell(

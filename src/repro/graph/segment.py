@@ -1,8 +1,8 @@
 """Segment / GroupBy primitives — the XLA re-expression of Arkouda's GroupBy.
 
 The paper's aggregation phase leans on Arkouda ``GroupBy`` + ``Broadcast``
-(§III-B2).  On TPU the same computation is a multi-operand ``lax.sort``
-followed by run detection (`run_starts`), run-id `cumsum`, and
+(§III-B2).  On TPU the same computation is a stable lexicographic sort
+(``sort_by_keys``) followed by run detection (`run_starts`), run-id `cumsum`, and
 ``segment_sum`` — every helper here is jit-safe with static shapes.
 """
 from __future__ import annotations
@@ -16,10 +16,24 @@ import jax.numpy as jnp
 def sort_by_keys(
     keys: Sequence[jax.Array], values: Sequence[jax.Array] = ()
 ) -> Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]:
-    """Stable lexicographic sort of ``values`` by ``keys`` (all same length)."""
-    operands = tuple(keys) + tuple(values)
-    out = jax.lax.sort(operands, num_keys=len(keys), is_stable=True)
-    return out[: len(keys)], out[len(keys):]
+    """Stable lexicographic sort of ``values`` by ``keys`` (all same length).
+
+    One pass per key, least significant first, each sorting (key,
+    position) pairs by both: the current position breaks ties, so every
+    pass is stable and the result is the order of one stable multi-key
+    sort.  Every pass is the same two-operand sort, which the TPU compiler
+    builds once: for v5e at 12.4M entries one stable sort over 3 keys + 1
+    value took 394 s to compile, a stable two-operand sort 63 s and this
+    one 33 s (JAX 0.9.0, libtpu 0.0.34, compiled on an 8-core x86 host).
+    The price is run time: on one v5e at 1,524,310 entries this takes
+    129 ms against 6.3 ms for the one stable sort, the per-pass gathers
+    being the cost."""
+    pos = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    perm = pos
+    for k in reversed(tuple(keys)):
+        _, order = jax.lax.sort((k[perm], pos), num_keys=2, is_stable=False)
+        perm = perm[order]
+    return tuple(k[perm] for k in keys), tuple(v[perm] for v in values)
 
 
 def run_starts(*sorted_keys: jax.Array) -> jax.Array:

@@ -96,7 +96,7 @@ def bin_rank_pallas(
         kern,
         grid=(Rp // r_blk,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             col(), col(),
         ],
         out_specs=col(),

@@ -18,7 +18,8 @@ accumulation over the DENSE contiguous community ids (DESIGN.md
      slot; the smallest key wins a round) continue linear probing; any
      survivor after the round budget raises the overflow fallback.
   3. *Rank*: per edge, the rank of its destination key within its bin row
-     (kernel.py on TPU / ref.py elsewhere — ``resolve_bin_impl``) plus a
+     (ref.py by default; kernel.py is the interpret-mode oracle —
+     ``resolve_bin_impl``) plus a
      per-row occupancy count and an exclusive ``cumsum`` over rows give the
      canonical front-compacted src-sorted output position with no sort.
   4. *Output*: three m-sized edge scatters — src/dst ids (duplicates write
@@ -39,8 +40,8 @@ from repro.graph import segment as seg
 from repro.graph.structure import Graph
 from repro.kernels.aggregation.kernel import bin_rank_pallas
 from repro.kernels.aggregation.ref import bin_rank_ref
-from repro.kernels.common import (bin_table_bytes, hash_u32_jnp,
-                                  pick_bin_width, resolve_bin_impl)
+from repro.kernels.common import (cdiv, hash_u32_jnp, pick_bin_width,
+                                  resolve_bin_impl)
 from repro.utils import telemetry
 
 
@@ -110,6 +111,33 @@ def insert_bins(
     return keys, resolved, overflow, rounds
 
 
+# Entries of the (edges, width) key-row gather per rank chunk (32 MiB of
+# int32).  Unchunked, that gather is the largest buffer of a level: at
+# com-youtube's level 0 (12.4M directed edges, width 64) it takes 12 GiB
+# of v5e HBM with its index array and lane padding.
+RANK_CHUNK_ELEMS = 1 << 23
+
+
+def bin_rank_chunked(keys_flat: jax.Array, cs: jax.Array, cd: jax.Array, *,
+                     width: int, empty: int) -> jax.Array:
+    """``bin_rank_ref`` over edge chunks of ``RANK_CHUNK_ELEMS // width``
+    edges (one chunk, i.e. the plain call, when the edges fit).  Ranks are
+    integer counts, so chunking cannot change them."""
+    m = cs.shape[0]
+    chunk = max(8, RANK_CHUNK_ELEMS // width)
+    if m <= chunk:
+        return bin_rank_ref(keys_flat, cs, cd, width=width, empty=empty)
+    nc = cdiv(m, chunk)
+    pad = nc * chunk - m
+    sink_row = keys_flat.shape[0] // width - 1   # padding ranks to 0
+    cs_p = jnp.pad(cs, (0, pad), constant_values=sink_row).reshape(nc, chunk)
+    cd_p = jnp.pad(cd, (0, pad), constant_values=empty).reshape(nc, chunk)
+    ranks = jax.lax.map(
+        lambda a: bin_rank_ref(keys_flat, a[0], a[1], width=width,
+                               empty=empty), (cs_p, cd_p))
+    return ranks.reshape(-1)[:m]
+
+
 def binned_coarsen(
     g: Graph,
     new_com: jax.Array,
@@ -139,7 +167,7 @@ def binned_coarsen(
     sentinel = jnp.int32(n)
     empty = int(n)
     active = g.edge_mask
-    impl_r = resolve_bin_impl(impl, bin_table_bytes(n, W), vmem_budget)
+    impl_r = resolve_bin_impl(impl)
 
     cs, cd = community_edge_keys(g, new_com)
     keys, _resolved, overflow, _rounds = insert_bins(
@@ -160,7 +188,8 @@ def binned_coarsen(
                 keys_flat, cs_c, cd, width=W, empty=empty,
                 row_block=row_block, vmem_budget=vmem_budget)
         else:
-            rank_e = bin_rank_ref(keys_flat, cs_c, cd, width=W, empty=empty)
+            rank_e = bin_rank_chunked(keys_flat, cs_c, cd, width=W,
+                                      empty=empty)
         epos = jnp.where(
             active, row_start[jnp.clip(cs, 0, n - 1)] + rank_e, m)
         # duplicate positions write identical values (all edges of a group

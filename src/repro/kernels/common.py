@@ -9,10 +9,18 @@ import jax.numpy as jnp
 
 from repro.utils import faultinject, telemetry
 
-# Default per-core VMEM capacity assumed by the budget policy (~16 MB/core on
-# contemporary TPUs).  Override per TPU generation with the
-# REPRO_VMEM_BUDGET_BYTES environment variable or the ``budget_bytes`` kwargs.
-DEFAULT_VMEM_BUDGET_BYTES = 16 << 20
+# Scoped VMEM a pallas_call may use without raising its own limit, per TPU
+# ``device_kind`` (source: the Pallas TPU documentation, "Pipelining" /
+# ``vmem_limit_bytes``: 16 MiB by default on v4 and v5e, 32 MiB on v6e).  A
+# TPU kind missing here is an error, never a default.  Off the chip the
+# v5e figure is used, so CPU rehearsals resolve the same layouts as v5e.
+# REPRO_VMEM_BUDGET_BYTES or the ``budget_bytes`` kwargs override it.
+VMEM_BUDGET_BY_KIND = {
+    "TPU v4": 16 << 20,
+    "TPU v5 lite": 16 << 20,
+    "TPU v6 lite": 32 << 20,
+}
+DEFAULT_VMEM_BUDGET_BYTES = VMEM_BUDGET_BY_KIND["TPU v5 lite"]
 VMEM_BUDGET_ENV = "REPRO_VMEM_BUDGET_BYTES"
 
 # Largest integer float32 accumulates exactly (24-bit mantissa).  Volume /
@@ -33,23 +41,50 @@ def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU (read at trace time)."""
+    return jax.default_backend() == "tpu"
+
+
 def default_interpret() -> bool:
-    """Pallas interpret mode: True unless running on a real TPU."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode: on the CPU only.  The kernels are Mosaic TPU
+    kernels, so any other backend is an error rather than a quiet
+    emulation."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on the {backend!r} backend")
+
+
+def default_vmem_budget() -> int:
+    """The scoped VMEM budget of the attached chip (``VMEM_BUDGET_BY_KIND``),
+    or the v5e figure off the chip."""
+    if not on_tpu():
+        return DEFAULT_VMEM_BUDGET_BYTES
+    kind = jax.devices()[0].device_kind
+    if kind not in VMEM_BUDGET_BY_KIND:
+        raise RuntimeError(
+            f"no VMEM budget known for TPU device_kind {kind!r}; add it to "
+            f"VMEM_BUDGET_BY_KIND or set {VMEM_BUDGET_ENV}")
+    return VMEM_BUDGET_BY_KIND[kind]
 
 
 def vmem_budget_bytes(budget_bytes: int | None = None) -> int:
     """Resolve the per-core VMEM byte budget.
 
     Precedence: explicit kwarg > REPRO_VMEM_BUDGET_BYTES env var > the
-    built-in ~16 MB default.  Read at trace time, so the resident/streamed
-    decision and row-block sizing are static per compiled program.
+    per-device_kind budget (``default_vmem_budget``).  Read at trace time,
+    so the resident/streamed decision and row-block sizing are static per
+    compiled program.
     """
     if budget_bytes is not None:
         b = int(budget_bytes)
     else:
         env = os.environ.get(VMEM_BUDGET_ENV)
-        b = int(env) if env else DEFAULT_VMEM_BUDGET_BYTES
+        b = int(env) if env else default_vmem_budget()
     if faultinject.is_active("vmem_starve"):
         # fault injection: collapse the budget so every capacity-adaptive
         # policy (resident/streamed tables, kernel/ref bin rank) lands in
@@ -277,6 +312,26 @@ def dist_comm_bytes_per_level(n: int, m_pad: int, h_cap: int,
 
 BIN_IMPLS = ("auto", "kernel", "ref")
 
+# Kernels whose in-kernel gathers Mosaic refuses on the TPU, with the
+# compiler's own message (JAX 0.9.0 / libtpu 0.0.34, v5e).  Mosaic lowers a
+# gather only as a same-shape 2-D ``take_along_axis``; these index a table
+# with arbitrary ids.  They stay the interpret-mode parity oracles on CPU,
+# and no TPU policy below ever picks them: an explicit request raises.
+TPU_UNLOWERABLE = {
+    "bin_rank": "NotImplementedError: Only 2D gather is supported",
+    "local_move.resident": "NotImplementedError: Only 2D gather is supported",
+    "local_move.streamed": "NotImplementedError: Only 2D gather is supported",
+}
+
+
+def require_lowerable(kernel: str) -> None:
+    """Raise when ``kernel`` is asked for on a TPU it cannot be compiled
+    for (``TPU_UNLOWERABLE``); a no-op elsewhere."""
+    if on_tpu() and kernel in TPU_UNLOWERABLE:
+        raise ValueError(
+            f"the {kernel} Pallas kernel does not lower on TPU "
+            f"(Mosaic: {TPU_UNLOWERABLE[kernel]})")
+
 
 def pick_bin_width(n_cap: int, m_cap: int) -> int:
     """Static per-src-community bin-row width for the sort-free aggregation
@@ -293,30 +348,21 @@ def pick_bin_width(n_cap: int, m_cap: int) -> int:
     return pick_ell_width(None, n_cap, m_cap)
 
 
-def bin_table_bytes(n_cap: int, width: int) -> int:
-    """HBM/VMEM footprint of the (n_cap+1, width) int32 bin-key table (the
-    +1 row is the sink for masked edges)."""
-    return 4 * (n_cap + 1) * width
-
-
-def resolve_bin_impl(impl: str, table_bytes: int,
-                     budget_bytes: int | None = None) -> str:
+def resolve_bin_impl(impl: str) -> str:
     """Kernel-vs-ref policy for the binned aggregation rank pass.
 
-    ``auto`` uses the Pallas kernel when running on a real TPU AND the bin
-    table fits HALF the VMEM budget (the resident-table contract of
-    DESIGN.md §Kernels — the other half covers the gathered (R_blk, W)
-    tiles and the double-buffered pipeline); otherwise the pure-jnp ref
-    path runs (interpret-mode emulation would only add per-grid-step
-    dispatch overhead off-TPU, and an over-budget table cannot be resident).
+    ``auto`` always resolves to the pure-jnp ref path: the kernel's row
+    gather does not lower on TPU (``TPU_UNLOWERABLE``), and off the chip
+    interpret-mode emulation would only add per-grid-step dispatch
+    overhead.  ``kernel`` is the explicit interpret-mode oracle; asking for
+    it on a TPU raises.
     """
     if impl not in BIN_IMPLS:
         raise ValueError(f"unknown bin impl {impl!r}, want one of {BIN_IMPLS}")
-    if impl != "auto":
+    if impl == "kernel":
+        require_lowerable("bin_rank")
         return impl
-    if table_bytes > vmem_budget_bytes(budget_bytes) // 2:
-        return "ref"
-    return "kernel" if jax.default_backend() == "tpu" else "ref"
+    return "ref"
 
 
 def resolve_table_mode(mode: str, table_bytes: int,
@@ -350,4 +396,13 @@ def tie_noise_jnp(a: jax.Array, b: jax.Array, seed: jax.Array, eps: float) -> ja
         a.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)
         ^ hash_u32_jnp(b.astype(jnp.uint32) + seed.astype(jnp.uint32))
     )
-    return h.astype(jnp.float32) * jnp.float32(eps / 4294967296.0)
+    return u32_to_f32(h) * jnp.float32(eps / 4294967296.0)
+
+
+def u32_to_f32(h: jax.Array) -> jax.Array:
+    """``h.astype(float32)`` for uint32 ``h``, bit for bit, from two exact
+    16-bit halves: their sum rounds once, exactly as the direct convert
+    does.  Mosaic has no uint32 → float32 cast."""
+    hi = (h >> 16).astype(jnp.int32).astype(jnp.float32) * jnp.float32(65536.0)
+    lo = (h & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi + lo

@@ -43,15 +43,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, sk: int,
 
     def body(ki, carry):
         m, l, acc = carry
-        # Index the leading singleton axis with a length-1 slice, not a bare
-        # int: interpret-mode discharge (_load_discharge_rule) chokes on
-        # scalar indices mixed into a dynamic-slice index tuple.
-        k_blk = pl.load(
-            k_ref, (pl.ds(0, 1), pl.ds(ki * block_k, block_k), slice(None))
-        )[0]
-        v_blk = pl.load(
-            v_ref, (pl.ds(0, 1), pl.ds(ki * block_k, block_k), slice(None))
-        )[0]
+        k_blk = k_ref[0, pl.ds(ki * block_k, block_k), :]
+        v_blk = v_ref[0, pl.ds(ki * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, k_blk.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (BQ, BK)

@@ -93,16 +93,25 @@ def window_flat(tab: jax.Array, slot: int, n_slots: int, fill) -> jax.Array:
 
 
 def _window_view(tab: jax.Array, slot: int, n_slots: int, fill) -> jax.Array:
-    """Overlapped (n_slots, 2·slot) window view of a flat (n+1,) table.
+    """Overlapped (n_slots, 1, 2·slot) window view of a flat (n+1,) table.
 
     Row k covers flat[k·slot : k·slot + 2·slot): window offsets get slot
     granularity from a plain BlockSpec index map even though block indices
-    are multiplied by the block shape.  Built per sweep from live tables by
-    pad + reshape + concat — XLA fuses it; the 2× copy lives in HBM, which
-    is the point of streaming.
+    are multiplied by the block shape.  The unit middle axis makes the
+    block's last two dims equal the array's, the form Mosaic accepts for a
+    one-row block (a (1, 2·slot) block of a 2-D view is refused: the
+    second-to-last block dim must be a multiple of 8).  Built per sweep
+    from live tables by pad + reshape + concat — XLA fuses it; the 2× copy
+    lives in HBM, which is the point of streaming.
     """
     t2 = window_flat(tab, slot, n_slots, fill).reshape(n_slots + 1, slot)
-    return jnp.concatenate([t2[:-1], t2[1:]], axis=1)
+    return jnp.concatenate([t2[:-1], t2[1:]], axis=1)[:, None, :]
+
+
+def _window_spec(slot: int) -> pl.BlockSpec:
+    """This grid step's (1, 2·slot) window of an overlapped view."""
+    return pl.BlockSpec((pl.squeezed, 1, 2 * slot),
+                        lambda i, wb: (wb[i], 0, 0))
 
 
 def _pad_tiles(rows, nbr, w, r_blk: int, sentinel: int):
@@ -218,7 +227,7 @@ def local_move_plp_pallas(
         kern,
         grid=(Rp // r_blk,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             col(), wide(), wide(),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
@@ -272,7 +281,7 @@ def local_move_plp_pallas_streamed(
             pl.BlockSpec((r_blk, W), lambda i, wb: (i, 0)),
             pl.BlockSpec((r_blk, W), lambda i, wb: (i, 0)),
             pl.BlockSpec((1, 1), lambda i, wb: (0, 0)),
-            pl.BlockSpec((1, 2 * S), lambda i, wb: (wb[i], 0)),
+            _window_spec(S),
         ],
         out_specs=[
             pl.BlockSpec((r_blk, 1), lambda i, wb: (i, 0)),
@@ -286,7 +295,7 @@ def local_move_plp_pallas_streamed(
             jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
             jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(
@@ -414,7 +423,7 @@ def local_move_louvain_pallas(
         sentinel=sentinel,
         singleton_rule=singleton_rule,
     )
-    any_spec = lambda: pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = lambda: pl.BlockSpec(memory_space=pl.ANY)
     wide = lambda: pl.BlockSpec((r_blk, W), lambda i: (i, 0))
     col = lambda: pl.BlockSpec((r_blk, 1), lambda i: (i, 0))
     out_cand, out_prop = pl.pallas_call(
@@ -476,7 +485,7 @@ def local_move_louvain_pallas_streamed(
         _local_move_louvain_streamed_kernel,
         sentinel=sentinel, singleton_rule=singleton_rule, slot=S,
     )
-    win = lambda: pl.BlockSpec((1, 2 * S), lambda i, wb: (wb[i], 0))
+    win = lambda: _window_spec(S)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb,),
@@ -499,7 +508,7 @@ def local_move_louvain_pallas_streamed(
             jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
             jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(
@@ -511,3 +520,4 @@ def local_move_louvain_pallas_streamed(
         ov_com, ov_vol, ov_size, ov_deg,
     )
     return out_cand[:R, 0], out_prop[:R, 0]
+

@@ -13,7 +13,9 @@ bucket, and reshapes the outputs back.
 Table layout selection (DESIGN.md §Kernels): ``table_mode`` picks between
 the VMEM-RESIDENT fast path and the WINDOWED STREAMED path; ``auto``
 resolves from the VMEM byte budget (``kernels.common.resolve_table_mode``)
-at trace time.  Streaming needs the per-row-block window metadata
+at trace time.  On a TPU the Pallas path is always GATHERED: XLA gathers
+the (rows, W) tiles and the label_argmax / delta_q kernels score them
+(``_resolve_mode``).  Streaming needs the per-row-block window metadata
 (``graph.ell.TableWindows``, passed duck-typed so the kernel layer stays
 free of graph-layer imports); without it the resident path is used.  Both
 pallas layouts and both pure-jnp oracles are bit-identical — the windowed
@@ -22,6 +24,7 @@ per-block ref the streamed kernel body runs.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -30,8 +33,14 @@ import jax.numpy as jnp
 from repro.kernels.common import (
     TABLE_LANE,
     default_interpret,
+    on_tpu,
+    pick_row_block,
+    require_lowerable,
     resolve_table_mode,
+    vmem_budget_bytes,
 )
+from repro.kernels.delta_q.kernel import delta_q_pallas
+from repro.kernels.label_argmax.kernel import label_argmax_pallas
 from repro.kernels.local_move.kernel import (
     _check_windows,
     _pad_tiles,
@@ -45,6 +54,10 @@ from repro.kernels.local_move.ref import (
     compose_louvain_tables,
     local_move_louvain_tables_ref,
     local_move_plp_ref,
+    louvain_gather,
+    louvain_score,
+    plp_gather,
+    plp_score,
 )
 
 
@@ -57,9 +70,26 @@ def _flatten(rows, nbr, w):
     )
 
 
+def gathered_row_block(width: int, rows: int,
+                       vmem_budget: Optional[int] = None) -> int:
+    """Rows per grid step of the gathered layout's scoring kernel: the
+    (R_blk, W, W) pairwise tensor and its temporaries within the VMEM
+    budget (a 1/32 share per pairwise f32 element leaves room for the
+    double-buffered (R_blk, W) tiles)."""
+    return min(pick_row_block(width, vmem_budget_bytes(vmem_budget) // 32),
+               rows)
+
+
 def _resolve_mode(table_mode: str, windows, n_tables: int, sentinel: int,
-                  vmem_budget: Optional[int]) -> str:
-    """Static resident-vs-streamed decision for one dispatch.
+                  vmem_budget: Optional[int], use_pallas: bool = False) -> str:
+    """Static table-layout decision for one dispatch.
+
+    On a TPU the Pallas path always takes the ``gathered`` layout (XLA
+    gathers the tiles, the label_argmax / delta_q kernels score them): the
+    resident and streamed
+    kernels gather in-kernel, which Mosaic refuses
+    (``kernels.common.TPU_UNLOWERABLE``), so asking for them there raises.
+    Elsewhere:
 
     ``auto`` additionally requires the STREAMED footprint to earn its keep:
 
@@ -73,12 +103,13 @@ def _resolve_mode(table_mode: str, windows, n_tables: int, sentinel: int,
       tested against — mediocre locality past the resident budget would
       otherwise stream windows that bust VMEM just the same.
 
-    Failing either check falls back to resident (on a real TPU a
-    past-budget resident layout may still fail to compile — the fix is
-    better locality or a finer ``block_rows``, see DESIGN.md §Kernels).
-    Explicit ``table_mode='streamed'`` is honored unchecked (the
+    Failing either check falls back to resident.  Explicit ``table_mode='streamed'`` is honored unchecked (the
     degenerate-window parity tests rely on it).
     """
+    if use_pallas and on_tpu():
+        if table_mode in ("resident", "streamed"):
+            require_lowerable(f"local_move.{table_mode}")
+        return "gathered"
     if windows is None:
         if table_mode == "streamed":
             raise ValueError(
@@ -133,10 +164,21 @@ def local_move_plp(
     lead = rows.shape
     rows_f, nbr_f, w_f = _flatten(rows, nbr, w)
     labels_ext = labels_ext.astype(jnp.int32)
-    mode = _resolve_mode(table_mode, windows, 1, sentinel, vmem_budget)
+    mode = _resolve_mode(table_mode, windows, 1, sentinel, vmem_budget,
+                         use_pallas)
     if use_pallas:
         interp = default_interpret() if interpret is None else interpret
-        if mode == "streamed":
+        if mode == "gathered":
+            nbr_lab, cur_lab, rows_n = plp_gather(
+                rows_f, nbr_f, labels_ext, sentinel=sentinel)
+            r_blk = gathered_row_block(nbr_f.shape[1], nbr_f.shape[0],
+                                       vmem_budget)
+            best, prop = plp_score(
+                nbr_lab, w_f, cur_lab, rows_n, seed, tie_eps=tie_eps,
+                sentinel=sentinel, argmax=functools.partial(
+                    label_argmax_pallas, interpret=interp, row_block=r_blk),
+            )
+        elif mode == "streamed":
             best, prop = local_move_plp_pallas_streamed(
                 rows_f, nbr_f, w_f, labels_ext, seed,
                 tie_eps=tie_eps, sentinel=sentinel, interpret=interp,
@@ -208,10 +250,21 @@ def local_move_louvain(
             size_ext.astype(jnp.int32), deg_ext.astype(jnp.float32),
             sentinel)
     com_v, volcom_v, sizecom_v, deg_v = composed
-    mode = _resolve_mode(table_mode, windows, 4, sentinel, vmem_budget)
+    mode = _resolve_mode(table_mode, windows, 4, sentinel, vmem_budget,
+                         use_pallas)
     if use_pallas:
         interp = default_interpret() if interpret is None else interpret
-        if mode == "streamed":
+        if mode == "gathered":
+            tiles = louvain_gather(rows_f, nbr_f, com_v, volcom_v, sizecom_v,
+                                   deg_v, sentinel=sentinel)
+            r_blk = gathered_row_block(nbr_f.shape[1], nbr_f.shape[0],
+                                       vmem_budget)
+            best, prop = louvain_score(
+                tiles[0], w_f, *tiles[1:], inv_vol, sentinel=sentinel,
+                singleton_rule=singleton_rule, argmax=functools.partial(
+                    delta_q_pallas, interpret=interp, row_block=r_blk),
+            )
+        elif mode == "streamed":
             best, prop = local_move_louvain_pallas_streamed(
                 rows_f, nbr_f, w_f, com_v, volcom_v, sizecom_v, deg_v,
                 inv_vol, sentinel=sentinel, singleton_rule=singleton_rule,

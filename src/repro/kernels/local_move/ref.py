@@ -53,6 +53,27 @@ def _gather(tab: jax.Array, ids: jax.Array, sentinel: int, fill,
     return jnp.where(ids < sentinel, tab[idx], fill)
 
 
+def plp_gather(rows, nbr, labels_ext, *, sentinel: int,
+               win_lo: Optional[jax.Array] = None):
+    """The PLP move's table reads: (nbr_lab[R, W], cur_lab[R], rows_n[R])."""
+    n = sentinel
+    nbr_lab = _gather(labels_ext, nbr, n, n, win_lo)
+    cur_lab = _gather(labels_ext, rows, n, n, win_lo)
+    return nbr_lab, cur_lab, jnp.where(rows < n, rows, n)
+
+
+def plp_score(nbr_lab, w, cur_lab, rows_n, seed, *, tie_eps: float,
+              sentinel: int,
+              argmax=label_argmax_ref) -> Tuple[jax.Array, jax.Array]:
+    """(best_label[R], propose[R]) from gathered tiles — the body of every
+    PLP layout.  ``argmax`` is ``label_argmax_ref`` or, for the gathered
+    TPU layout, its Pallas kernel (same signature, bit-identical)."""
+    best_lab, best_score, cur_score = argmax(
+        nbr_lab, w, cur_lab, rows_n, seed, tie_eps, sentinel
+    )
+    return best_lab, (best_lab >= 0) & (best_score > cur_score)
+
+
 def local_move_plp_ref(
     rows: jax.Array,        # (R,) int32 vertex id per row (sentinel = pad)
     nbr: jax.Array,         # (R, W) int32 neighbor ids (sentinel = pad)
@@ -66,14 +87,9 @@ def local_move_plp_ref(
     win_lo: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """(best_label[R], propose[R]) for the PLP move, gathers included."""
-    n = sentinel
-    nbr_lab = _gather(labels_ext, nbr, n, n, win_lo)
-    cur_lab = _gather(labels_ext, rows, n, n, win_lo)
-    rows_n = jnp.where(rows < n, rows, n)
-    best_lab, best_score, cur_score = label_argmax_ref(
-        nbr_lab, w, cur_lab, rows_n, seed, tie_eps, sentinel
-    )
-    return best_lab, (best_lab >= 0) & (best_score > cur_score)
+    tiles = plp_gather(rows, nbr, labels_ext, sentinel=sentinel, win_lo=win_lo)
+    return plp_score(tiles[0], w, *tiles[1:], seed, tie_eps=tie_eps,
+                     sentinel=sentinel)
 
 
 def compose_louvain_tables(
@@ -96,6 +112,36 @@ def compose_louvain_tables(
     return com_ext, vol_ext[idx], size_ext[idx], deg_ext
 
 
+def louvain_gather(rows, nbr, com_v, volcom_v, sizecom_v, deg_v, *,
+                   sentinel: int, win_lo: Optional[jax.Array] = None):
+    """The Eq. 1 table reads on vertex-composed tables: (cand[R, W],
+    cur[R], deg[R], vol_cand[R, W], vol_cur[R], size_cand[R, W],
+    size_cur[R])."""
+    n = sentinel
+    return (
+        _gather(com_v, nbr, n, n, win_lo),
+        _gather(com_v, rows, n, n, win_lo),
+        _gather(deg_v, rows, n, 0.0, win_lo),
+        _gather(volcom_v, nbr, n, 0.0, win_lo),
+        _gather(volcom_v, rows, n, 0.0, win_lo),
+        _gather(sizecom_v, nbr, n, 0, win_lo),
+        _gather(sizecom_v, rows, n, 0, win_lo),
+    )
+
+
+def louvain_score(cand, w, cur, deg, vol_cand, vol_cur, size_cand, size_cur,
+                  inv_vol, *, sentinel: int, singleton_rule: bool,
+                  argmax=delta_q_ref) -> Tuple[jax.Array, jax.Array]:
+    """(best_community[R], propose[R]) from gathered tiles — the body of
+    every Louvain layout; the gain test is Eq. 1 > 0.  ``argmax`` is
+    ``delta_q_ref`` or, for the gathered TPU layout, its Pallas kernel."""
+    best_cand, best_gain = argmax(
+        cand, w, cur, deg, vol_cand, vol_cur, size_cand, size_cur, inv_vol,
+        sentinel=sentinel, singleton_rule=singleton_rule,
+    )
+    return best_cand, (best_cand >= 0) & (best_gain > 0.0)
+
+
 def local_move_louvain_tables_ref(
     rows: jax.Array,       # (R,) int32 vertex id per row (sentinel = pad)
     nbr: jax.Array,        # (R, W) int32 neighbor ids (sentinel = pad)
@@ -113,24 +159,14 @@ def local_move_louvain_tables_ref(
     """(best_community[R], propose[R]) on vertex-composed tables (Eq. 1).
 
     The single scoring path shared by the resident kernel (full tables,
-    ``win_lo=None``), the streamed kernel (window slices + rebase), and the
-    pure-jnp windowed ref — kernel ≡ ref holds structurally.
+    ``win_lo=None``), the streamed kernel (window slices + rebase), the
+    gathered TPU layout (``louvain_score`` over the ``delta_q`` kernel) and
+    the pure-jnp windowed ref — kernel ≡ ref holds structurally.
     """
-    n = sentinel
-    cand = _gather(com_v, nbr, n, n, win_lo)
-    cur = _gather(com_v, rows, n, n, win_lo)
-    best_cand, best_gain = delta_q_ref(
-        cand, w, cur,
-        _gather(deg_v, rows, n, 0.0, win_lo),
-        _gather(volcom_v, nbr, n, 0.0, win_lo),
-        _gather(volcom_v, rows, n, 0.0, win_lo),
-        _gather(sizecom_v, nbr, n, 0, win_lo),
-        _gather(sizecom_v, rows, n, 0, win_lo),
-        inv_vol,
-        sentinel=sentinel,
-        singleton_rule=singleton_rule,
-    )
-    return best_cand, (best_cand >= 0) & (best_gain > 0.0)
+    g = louvain_gather(rows, nbr, com_v, volcom_v, sizecom_v, deg_v,
+                       sentinel=sentinel, win_lo=win_lo)
+    return louvain_score(g[0], w, *g[1:], inv_vol, sentinel=sentinel,
+                         singleton_rule=singleton_rule)
 
 
 def local_move_louvain_ref(

@@ -13,6 +13,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 
@@ -266,6 +267,30 @@ class TestDegradationLadder:
         # the report shows the whole descent was tried before giving up
         assert [d["from"] for d in ei.value.report.degradations] == \
             ["pallas", "ell"]
+
+    @pytest.mark.parametrize("algo", ["louvain", "plp"])
+    def test_no_backend_descent_on_tpu(self, graph, monkeypatch, algo):
+        """On a TPU a kernel failure (a Pallas lowering or compile error)
+        surfaces as KernelError; it never turns into a quiet ell run."""
+        louvain_mod = importlib.import_module("repro.core.louvain")
+        plp_mod = importlib.import_module("repro.core.plp")
+
+        def broken(*a, **k):
+            raise NotImplementedError("synthetic Mosaic lowering failure")
+
+        target = ((louvain_mod, "_louvain_pipeline") if algo == "louvain"
+                  else (plp_mod, "_plp_once"))
+        monkeypatch.setattr(*target, broken)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert louvain_mod.backend_descent("pallas") is None
+        telemetry.reset()
+        with pytest.raises(KernelError, match="synthetic Mosaic") as ei:
+            if algo == "louvain":
+                louvain_mod.louvain(graph, LouvainConfig(backend="pallas"))
+            else:
+                plp_mod.plp(graph, PLPConfig(backend="pallas"))
+        assert ei.value.report.degradations == []
+        assert telemetry.get("ladder.backend_descent") == 0
 
     def test_capacity_retry_on_single_capacity_program(self, graph,
                                                        monkeypatch):

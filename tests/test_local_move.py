@@ -91,6 +91,49 @@ def test_louvain_kernel_matches_ref(width, singleton_rule):
     np.testing.assert_array_equal(np.asarray(prop_k), np.asarray(prop_r))
 
 
+@pytest.fixture
+def tpu_layout(monkeypatch):
+    """The pallas path's TPU policy (the gathered layout), with the kernel
+    still in interpret mode on this CPU."""
+    monkeypatch.setattr(lm_ops, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("width", BUCKET_WIDTHS)
+def test_plp_gathered_layout_matches_ref(width, tpu_layout):
+    """The TPU layout: XLA-gathered tiles scored by ``label_argmax_pallas``
+    (``delta_q_pallas`` for Louvain)."""
+    rows = 8 if width >= 256 else 32
+    n = 64
+    r_ids, nbr, w, labels_ext, _ = _tiles(rows, width, n, seed=width + 2)
+    kw = dict(tie_eps=0.25, sentinel=n)
+    seed = jnp.uint32(11)
+    best_k, prop_k = lm_ops.local_move_plp(
+        r_ids, nbr, w, labels_ext, seed, use_pallas=True, interpret=True,
+        **kw)
+    best_r, prop_r = lm_ops.local_move_plp(
+        r_ids, nbr, w, labels_ext, seed, use_pallas=False, **kw)
+    np.testing.assert_array_equal(np.asarray(best_k), np.asarray(best_r))
+    np.testing.assert_array_equal(np.asarray(prop_k), np.asarray(prop_r))
+
+
+@pytest.mark.parametrize("width", BUCKET_WIDTHS)
+@pytest.mark.parametrize("singleton_rule", [True, False])
+def test_louvain_gathered_layout_matches_ref(width, singleton_rule,
+                                             tpu_layout):
+    rows = 8 if width >= 256 else 32
+    n = 64
+    r_ids, nbr, w, _, tables = _tiles(rows, width, n, seed=width + 3)
+    kw = dict(sentinel=n, singleton_rule=singleton_rule)
+    vol_total = jnp.float32(41.0)
+    best_k, prop_k = lm_ops.local_move_louvain(
+        r_ids, nbr, w, vol_total=vol_total, use_pallas=True, interpret=True,
+        **tables, **kw)
+    best_r, prop_r = lm_ops.local_move_louvain(
+        r_ids, nbr, w, vol_total=vol_total, use_pallas=False, **tables, **kw)
+    np.testing.assert_array_equal(np.asarray(best_k), np.asarray(best_r))
+    np.testing.assert_array_equal(np.asarray(prop_k), np.asarray(prop_r))
+
+
 def test_fused_matches_legacy_two_step():
     """The fused kernel must reproduce the legacy gather-outside two-step
     (jnp gathers into (rows, W) tiles + label_argmax / delta_q kernels)
@@ -194,6 +237,22 @@ def test_sweep_tail_heavy_bitwise_equal(evaluator):
     np.testing.assert_array_equal(
         np.asarray(res["ell"].labels), np.asarray(res["pallas"].labels))
     assert res["ell"].delta_n_history == res["pallas"].delta_n_history
+
+
+@pytest.mark.parametrize("evaluator", ["plp", "louvain"])
+def test_sweep_gathered_matches_segment(evaluator, tpu_layout):
+    """The gathered layout (the pallas backend's only layout on TPU) over a
+    whole fused phase ≡ the segment evaluator, labels and histories."""
+    g = _graph(seed=17)
+    res = {}
+    for backend in ("segment", "pallas"):
+        spec = EngineSpec(evaluator=evaluator, backend=backend,
+                          max_sweeps=30, move_prob=0.75)
+        eng = SweepEngine(g, spec)
+        res[backend] = eng.run_phase(*eng.singleton_state(), seed=9)
+    np.testing.assert_array_equal(
+        np.asarray(res["segment"].labels), np.asarray(res["pallas"].labels))
+    assert res["pallas"].delta_n_history == res["segment"].delta_n_history
 
 
 # ------------------------------------------------------- windowed streaming
