@@ -68,8 +68,10 @@ def remap_communities(com: jax.Array, vertex_mask: jax.Array) -> Tuple[jax.Array
     """
     n = com.shape[0]
     sentinel = jnp.int32(n)
-    table, n_comm = seg.contiguize_ids(com, vertex_mask, n)
-    new_com = jnp.where(vertex_mask, table[jnp.clip(com, 0, n - 1)], sentinel)
+    with jax.named_scope("repro.aggregate"):
+        table, n_comm = seg.contiguize_ids(com, vertex_mask, n)
+        new_com = jnp.where(vertex_mask, table[jnp.clip(com, 0, n - 1)],
+                            sentinel)
     return new_com, n_comm
 
 
@@ -236,10 +238,11 @@ def remap_and_coarsen_by(
     if method not in AGGREGATION_METHODS:
         raise ValueError(
             f"unknown aggregation {method!r}, want one of {AGGREGATION_METHODS}")
-    if method == "sort":
-        return remap_and_coarsen(g, com)
-    return remap_and_coarsen_binned(
-        g, com, force_overflow="binned_overflow" in faults)
+    with jax.named_scope("repro.aggregate"):
+        if method == "sort":
+            return remap_and_coarsen(g, com)
+        return remap_and_coarsen_binned(
+            g, com, force_overflow="binned_overflow" in faults)
 
 
 def shrink_graph(g: Graph, n_max: int, m_max: int) -> Graph:
@@ -253,18 +256,19 @@ def shrink_graph(g: Graph, n_max: int, m_max: int) -> Graph:
     changes with the capacity.
     """
     sent = jnp.int32(n_max)
-    em = g.edge_mask[:m_max]
-    return Graph(
-        src=jnp.where(em, g.src[:m_max], sent),
-        dst=jnp.where(em, g.dst[:m_max], sent),
-        w=jnp.where(em, g.w[:m_max], 0.0),
-        edge_mask=em,
-        n_valid=g.n_valid,
-        m_valid=g.m_valid,
-        n_max=int(n_max),
-        m_max=int(m_max),
-        sorted_by=g.sorted_by,
-    )
+    with jax.named_scope("repro.aggregate"):
+        em = g.edge_mask[:m_max]
+        return Graph(
+            src=jnp.where(em, g.src[:m_max], sent),
+            dst=jnp.where(em, g.dst[:m_max], sent),
+            w=jnp.where(em, g.w[:m_max], 0.0),
+            edge_mask=em,
+            n_valid=g.n_valid,
+            m_valid=g.m_valid,
+            n_max=int(n_max),
+            m_max=int(m_max),
+            sorted_by=g.sorted_by,
+        )
 
 
 @jax.jit

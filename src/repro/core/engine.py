@@ -386,11 +386,13 @@ def device_phase(spec: EngineSpec, g: Graph, ell, labels, active, it0, seed,
     Must be called under an enclosing trace/jit; returns the raw loop outputs
     ``(labels, active, sweeps, dn_hist, act_hist)`` with everything device-
     resident.  ``SweepEngine.run_phase`` is the standalone-dispatch wrapper
-    around the same loop.
+    around the same loop.  Every operation of the phase sits in the
+    ``repro.local_move`` scope, whichever program embeds it.
     """
-    step = make_step(spec, g, ell, restrict)
-    return phase_loop(step, labels, active, it0, seed,
-                      spec.max_sweeps, spec.threshold)
+    with jax.named_scope("repro.local_move"):
+        step = make_step(spec, g, ell, restrict)
+        return phase_loop(step, labels, active, it0, seed,
+                          spec.max_sweeps, spec.threshold)
 
 
 def _donate_labels() -> bool:
@@ -412,7 +414,8 @@ def _fused_phase_fn(spec: EngineSpec, donate: bool):
 @program_cache("engine.step", maxsize=128)
 def _step_fn(spec: EngineSpec):
     def one_sweep(g, ell, labels, active, it, seed, restrict):
-        return make_step(spec, g, ell, restrict)(labels, active, it, seed)
+        with jax.named_scope("repro.local_move"):
+            return make_step(spec, g, ell, restrict)(labels, active, it, seed)
 
     return jax.jit(one_sweep)
 

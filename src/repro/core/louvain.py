@@ -312,18 +312,14 @@ def _refine_spec(cfg: LouvainConfig,
 
 # ------------------------------------------------------------ transfer hooks
 
-_transfer_count = 0   # incremented on every pipeline readback (test hook)
-_stage_sync_count = 0  # incremented on every cascade stage-boundary sync
-
 
 def _readback(tree):
     """The ONE bulk device→host transfer of the fused pipeline.
 
     Every host materialization of results in the ``pipeline_fused`` path
     flows through this function, so tests can count transfers by
-    monkeypatching it (or by reading ``_transfer_count``)."""
-    global _transfer_count
-    _transfer_count += 1
+    monkeypatching it (or by reading the ``louvain.readback`` counter)."""
+    telemetry.bump("louvain.readback")
     return jax.device_get(tree)
 
 
@@ -333,9 +329,8 @@ def _stage_sync(tree):
     or where to descend, and the next stage's traced-ELL width.  Counted
     separately from the one bulk ``_readback`` so tests can assert the
     cascade's transfer accounting; a degenerate (single-capacity) schedule
-    never syncs."""
-    global _stage_sync_count
-    _stage_sync_count += 1
+    never syncs (the ``louvain.stage_sync`` counter)."""
+    telemetry.bump("louvain.stage_sync")
     done, level, nv, mv, max_deg = jax.device_get(tree)
     return bool(done), int(level), int(nv), int(mv), int(max_deg)
 
@@ -430,9 +425,11 @@ def _build_stage(spec0: Optional[EngineSpec], spec_coarse: EngineSpec,
                     # Leiden: aggregate by the REFINED partition; seed the
                     # next level's local-moving with each super-vertex's
                     # macro id (paper-order: refinement only when not done)
-                    ref, _, _, _, _ = device_phase(
-                        refine_spec, cur, None, arange_n, vmask,
-                        it0 + jnp.uint32(REFINE_IT_OFFSET), seed, restrict=com)
+                    with jax.named_scope("repro.refine"):
+                        ref, _, _, _, _ = device_phase(
+                            refine_spec, cur, None, arange_n, vmask,
+                            it0 + jnp.uint32(REFINE_IT_OFFSET), seed,
+                            restrict=com)
                     new_ref, n_ref, nxt_r = aggregation.remap_and_coarsen_by(
                         agg_method, cur, ref, faults)
                     # macro seed as the CONTIGUIZED macro id (all members of
@@ -524,10 +521,11 @@ def _build_stage(spec0: Optional[EngineSpec], spec_coarse: EngineSpec,
             max_deg = jnp.max(jnp.where(arange_n < nv, deg_cnt, 0))
 
         def finalize(_):
-            final_assign, n_final = aggregation.remap_communities(
-                macro, g0.vertex_mask())
-            return (final_assign, n_final,
-                    modularity(g0, final_assign, promote=promote))
+            with jax.named_scope("repro.finalize"):
+                final_assign, n_final = aggregation.remap_communities(
+                    macro, g0.vertex_mask())
+                return (final_assign, n_final,
+                        modularity(g0, final_assign, promote=promote))
 
         if next_caps is None:
             final_assign, n_final, q_final = finalize(None)
@@ -739,20 +737,24 @@ def _louvain_pipeline(g: Graph, cfg: LouvainConfig,
 
     with timer.phase("pipeline"):
         while True:
-            fn = _stage_fn(spec0 if k == 0 else None,
-                           _cascade_coarse_spec(cfg, cascade, width, faults),
-                           refine_spec, cfg.max_levels, cfg.track_modularity,
-                           caps[k + 1] if k + 1 < len(caps) else None,
-                           cfg.aggregation, faults, promote)
-            (arrays, assign, init_com, macro, hists, level, done, nv, mv,
-             max_deg, final_assign, n_final, q_final) = fn(
-                g_k, ell_k, g0, seed_a, assign, init_com, macro, level,
-                hists)
+            with telemetry.span("repro.louvain.dispatch", stage=k,
+                                n_cap=caps[k][0], m_cap=caps[k][1]):
+                fn = _stage_fn(
+                    spec0 if k == 0 else None,
+                    _cascade_coarse_spec(cfg, cascade, width, faults),
+                    refine_spec, cfg.max_levels, cfg.track_modularity,
+                    caps[k + 1] if k + 1 < len(caps) else None,
+                    cfg.aggregation, faults, promote)
+                (arrays, assign, init_com, macro, hists, level, done, nv, mv,
+                 max_deg, final_assign, n_final, q_final) = fn(
+                    g_k, ell_k, g0, seed_a, assign, init_com, macro, level,
+                    hists)
             stage_idxs.append(k)
             if k + 1 >= len(caps):
                 break
-            done_h, level_h, nv_h, mv_h, max_deg_h = _stage_sync(
-                (done, level, nv, mv, max_deg))
+            with telemetry.span("repro.louvain.stage_sync"):
+                done_h, level_h, nv_h, mv_h, max_deg_h = _stage_sync(
+                    (done, level, nv, mv, max_deg))
             if done_h or level_h >= cfg.max_levels:
                 break
             # descend to the SMALLEST capacity the carried graph fits, so a
@@ -771,7 +773,9 @@ def _louvain_pipeline(g: Graph, cfg: LouvainConfig,
                     "cascade invariant violated: stage exited without "
                     f"done/budget and ({nv_h}, {mv_h}) fits no capacity in "
                     f"{caps[k + 1:]}")
-            g_k, init_com = _shrink_fn(*caps[k], *caps[k2])(arrays, init_com)
+            with telemetry.span("repro.louvain.shrink"):
+                g_k, init_com = _shrink_fn(*caps[k], *caps[k2])(arrays,
+                                                                init_com)
             ell_k = None
             k = k2
             width = pick_ell_width(max_deg_h, *caps[k])
@@ -786,7 +790,8 @@ def _louvain_pipeline(g: Graph, cfg: LouvainConfig,
                     "injected preemption at cascade stage boundary "
                     f"(entering stage k={k})")
 
-        out = _readback((final_assign, n_final, level, q_final) + hists)
+        with telemetry.span("repro.louvain.readback"):
+            out = _readback((final_assign, n_final, level, q_final) + hists)
     (final_assign, n_final, levels, q, mod_hist, sweeps_hist, ncomm_hist,
      dn_hist, bad_w) = out
 
@@ -797,24 +802,25 @@ def _louvain_pipeline(g: Graph, cfg: LouvainConfig,
             "non-finite edge weight detected inside the fused level loop")
     if ckpt_fp is not None:
         _ckpt_clear(cfg.checkpoint_dir)
-    levels = int(levels)
-    sweeps_per_level = [int(s) for s in sweeps_hist[:levels]]
-    return LouvainResult(
-        labels=np.asarray(final_assign),
-        n_communities=int(n_final),
-        levels=levels,
-        modularity=float(q),
-        modularity_history=(
-            [float(x) for x in mod_hist[:levels]]
-            if cfg.track_modularity else []),
-        sweeps_per_level=sweeps_per_level,
-        timer=timer,
-        n_comm_per_level=[int(x) for x in ncomm_hist[:levels]],
-        delta_n_per_level=[
-            [int(x) for x in row[:s]]
-            for row, s in zip(dn_hist[:levels], sweeps_per_level)],
-        cascade_stages=[caps[j] for j in stage_idxs],
-    )
+    with telemetry.span("repro.louvain.result"):
+        levels = int(levels)
+        sweeps_per_level = [int(s) for s in sweeps_hist[:levels]]
+        return LouvainResult(
+            labels=np.asarray(final_assign),
+            n_communities=int(n_final),
+            levels=levels,
+            modularity=float(q),
+            modularity_history=(
+                [float(x) for x in mod_hist[:levels]]
+                if cfg.track_modularity else []),
+            sweeps_per_level=sweeps_per_level,
+            timer=timer,
+            n_comm_per_level=[int(x) for x in ncomm_hist[:levels]],
+            delta_n_per_level=[
+                [int(x) for x in row[:s]]
+                for row, s in zip(dn_hist[:levels], sweeps_per_level)],
+            cascade_stages=[caps[j] for j in stage_idxs],
+        )
 
 
 # ------------------------------------------------------------ refinement
@@ -883,52 +889,53 @@ def louvain(g: Graph, cfg: LouvainConfig = LouvainConfig(),
     everything attempted is recorded in ``result.run_report``.  The clean
     path runs exactly one attempt with default fault/promotion state, so
     its traces, transfer counts and results are unchanged."""
-    report = RunReport(faults=sorted(faultinject.active()))
-    if g.n_max == 0:
-        return _trivial_result(report)
-    faults = frozenset(faultinject.active())
-    promote = accum_needs_promotion(g.m_max)
-    if promote:
-        report.warnings.append("precision:f32_accum_risk"
-                               if not jax.config.jax_enable_x64
-                               else "precision:promoted_f64")
-    cfg_try = cfg
-    while True:
-        try:
-            if cfg_try.pipeline_fused and cfg_try.fused:
-                res = _louvain_pipeline(g, cfg_try, g_original, faults,
-                                        promote)
-            else:
-                res = _louvain_per_level(g, cfg_try, g_original, faults,
-                                         promote)
-            break
-        except CapacityError as err:
-            if cfg_try.capacity_schedule == "none":
+    with telemetry.span("repro.louvain"):
+        report = RunReport(faults=sorted(faultinject.active()))
+        if g.n_max == 0:
+            return _trivial_result(report)
+        faults = frozenset(faultinject.active())
+        promote = accum_needs_promotion(g.m_max)
+        if promote:
+            report.warnings.append("precision:f32_accum_risk"
+                                   if not jax.config.jax_enable_x64
+                                   else "precision:promoted_f64")
+        cfg_try = cfg
+        while True:
+            try:
+                if cfg_try.pipeline_fused and cfg_try.fused:
+                    res = _louvain_pipeline(g, cfg_try, g_original, faults,
+                                            promote)
+                else:
+                    res = _louvain_per_level(g, cfg_try, g_original,
+                                             faults, promote)
+                break
+            except CapacityError as err:
+                if cfg_try.capacity_schedule == "none":
+                    err.report = report
+                    raise
+                telemetry.bump("ladder.capacity_retry")
+                report.retries.append({
+                    "kind": "capacity",
+                    "from": repr(cfg_try.capacity_schedule), "to": "none",
+                    "error": str(err)})
+                cfg_try = cfg_try.replace(capacity_schedule="none")
+            except CommunityDetectionError as err:
                 err.report = report
                 raise
-            telemetry.bump("ladder.capacity_retry")
-            report.retries.append({
-                "kind": "capacity",
-                "from": repr(cfg_try.capacity_schedule), "to": "none",
-                "error": str(err)})
-            cfg_try = cfg_try.replace(capacity_schedule="none")
-        except CommunityDetectionError as err:
-            err.report = report
-            raise
-        except Exception as err:  # noqa: BLE001 — the backend-descent rung
-            nxt = backend_descent(cfg_try.backend)
-            if nxt is None:
-                raise KernelError(
-                    f"backend {cfg_try.backend!r} failed with no descent "
-                    f"left: {type(err).__name__}: {err}",
-                    report=report) from err
-            telemetry.bump("ladder.backend_descent")
-            report.degradations.append({
-                "kind": "backend_descent",
-                "from": cfg_try.backend, "to": nxt,
-                "error": f"{type(err).__name__}: {err}"})
-            cfg_try = cfg_try.replace(backend=nxt)
-    return _finalize_report(res, cfg_try, report)
+            except Exception as err:  # noqa: BLE001 — backend-descent rung
+                nxt = backend_descent(cfg_try.backend)
+                if nxt is None:
+                    raise KernelError(
+                        f"backend {cfg_try.backend!r} failed with no descent "
+                        f"left: {type(err).__name__}: {err}",
+                        report=report) from err
+                telemetry.bump("ladder.backend_descent")
+                report.degradations.append({
+                    "kind": "backend_descent",
+                    "from": cfg_try.backend, "to": nxt,
+                    "error": f"{type(err).__name__}: {err}"})
+                cfg_try = cfg_try.replace(backend=nxt)
+        return _finalize_report(res, cfg_try, report)
 
 
 def _tphase(timer: Timer, name: str, level: int, per_level: bool):

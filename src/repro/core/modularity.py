@@ -62,20 +62,22 @@ def modularity(g: Graph, com: jax.Array, *, promote: bool = False) -> jax.Array:
     from repro.kernels.common import accum_dtype
 
     acc = accum_dtype(promote)
-    if acc == jnp.float32:
-        vol_v = g.total_volume()
-        w_in = intra_weight(g, com)
-        vol_c = community_volumes(g, com)
-    else:
-        wm = jnp.where(g.edge_mask, g.w, 0.0).astype(acc)
-        vol_v = jnp.sum(wm)
-        same = com[g.src] == com[g.dst]
-        w_in = jnp.sum(jnp.where(same, wm, jnp.zeros((), acc)))
-        deg = jax.ops.segment_sum(wm, g.src, num_segments=g.n_max)
-        vol_c = jax.ops.segment_sum(deg, com, num_segments=g.n_max)
-    safe = jnp.where(vol_v > 0, vol_v, jnp.ones((), vol_v.dtype))
-    q = w_in / safe - jnp.sum((vol_c / safe) ** 2)
-    return jnp.where(vol_v > 0, q, jnp.zeros((), q.dtype)).astype(jnp.float32)
+    with jax.named_scope("repro.modularity"):
+        if acc == jnp.float32:
+            vol_v = g.total_volume()
+            w_in = intra_weight(g, com)
+            vol_c = community_volumes(g, com)
+        else:
+            wm = jnp.where(g.edge_mask, g.w, 0.0).astype(acc)
+            vol_v = jnp.sum(wm)
+            same = com[g.src] == com[g.dst]
+            w_in = jnp.sum(jnp.where(same, wm, jnp.zeros((), acc)))
+            deg = jax.ops.segment_sum(wm, g.src, num_segments=g.n_max)
+            vol_c = jax.ops.segment_sum(deg, com, num_segments=g.n_max)
+        safe = jnp.where(vol_v > 0, vol_v, jnp.ones((), vol_v.dtype))
+        q = w_in / safe - jnp.sum((vol_c / safe) ** 2)
+        return jnp.where(vol_v > 0, q,
+                         jnp.zeros((), q.dtype)).astype(jnp.float32)
 
 
 def delta_q_from_score(score: jax.Array, vol_v: jax.Array) -> jax.Array:

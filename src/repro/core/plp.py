@@ -107,38 +107,40 @@ def plp(g: Graph, cfg: PLPConfig = PLPConfig(), ell_graph=None) -> PLPResult:
     descend the ``pallas → ell → segment`` ladder (bit-identical on clean
     input; never on a TPU), iteration-budget exhaustion is flagged as a watchdog warning,
     and everything attempted lands in ``result.run_report``."""
-    from repro.core.louvain import backend_descent
+    with telemetry.span("repro.plp"):
+        from repro.core.louvain import backend_descent
 
-    report = RunReport(faults=sorted(faultinject.active()))
-    if g.n_max == 0:
-        return PLPResult(labels=np.zeros((0,), np.int32), iterations=0,
-                         delta_n_history=[], active_history=[], timer=Timer(),
-                         run_report=report)
-    faults = frozenset(faultinject.active())
-    cfg_try = cfg
-    while True:
-        try:
-            res = _plp_once(g, cfg_try, ell_graph, faults)
-            break
-        except CommunityDetectionError as err:
-            err.report = report
-            raise
-        except Exception as err:  # noqa: BLE001 — the backend-descent rung
-            nxt = backend_descent(cfg_try.backend)
-            if nxt is None:
-                raise KernelError(
-                    f"backend {cfg_try.backend!r} failed with no descent "
-                    f"left: {type(err).__name__}: {err}",
-                    report=report) from err
-            telemetry.bump("ladder.backend_descent")
-            report.degradations.append({
-                "kind": "backend_descent",
-                "from": cfg_try.backend, "to": nxt,
-                "error": f"{type(err).__name__}: {err}"})
-            # a descended run no longer uses the caller's ELL layout
-            ell_graph = None
-            cfg_try = cfg_try.replace(backend=nxt)
-    if res.iterations >= cfg_try.max_iterations:
-        report.warnings.append("watchdog:max_iterations")
-    res.run_report = report
-    return res
+        report = RunReport(faults=sorted(faultinject.active()))
+        if g.n_max == 0:
+            return PLPResult(labels=np.zeros((0,), np.int32),
+                             iterations=0, delta_n_history=[],
+                             active_history=[], timer=Timer(),
+                             run_report=report)
+        faults = frozenset(faultinject.active())
+        cfg_try = cfg
+        while True:
+            try:
+                res = _plp_once(g, cfg_try, ell_graph, faults)
+                break
+            except CommunityDetectionError as err:
+                err.report = report
+                raise
+            except Exception as err:  # noqa: BLE001 — backend-descent rung
+                nxt = backend_descent(cfg_try.backend)
+                if nxt is None:
+                    raise KernelError(
+                        f"backend {cfg_try.backend!r} failed with no descent "
+                        f"left: {type(err).__name__}: {err}",
+                        report=report) from err
+                telemetry.bump("ladder.backend_descent")
+                report.degradations.append({
+                    "kind": "backend_descent",
+                    "from": cfg_try.backend, "to": nxt,
+                    "error": f"{type(err).__name__}: {err}"})
+                # a descended run no longer uses the caller's ELL layout
+                ell_graph = None
+                cfg_try = cfg_try.replace(backend=nxt)
+        if res.iterations >= cfg_try.max_iterations:
+            report.warnings.append("watchdog:max_iterations")
+        res.run_report = report
+        return res

@@ -193,6 +193,29 @@ def from_numpy_edges(
     * ``validate`` runs ``validate_graph`` on the result (None defers to the
       module-level ``DEFAULT_VALIDATE``, flipped on by the test conftest)
     """
+    with telemetry.span("repro.ingest"):
+        with telemetry.span("repro.ingest.canonicalize"):
+            src, dst, ww, n = _symmetrize_sorted(u, v, w, n, dedup, sort_by)
+        with telemetry.span("repro.ingest.to_device"):
+            g = graph_from_arrays(
+                jnp.asarray(src, dtype=jnp.int32),
+                jnp.asarray(dst, dtype=jnp.int32),
+                jnp.asarray(ww, dtype=jnp.float32),
+                n_max=n,
+                m_max=m_max,
+                n_valid=n,
+                sorted_by=sort_by,
+                validate=False,  # full validation below covers it
+            )
+        if _resolve_validate(validate):
+            with telemetry.span("repro.ingest.validate"):
+                validate_graph(g)
+        return g
+
+
+def _symmetrize_sorted(u, v, w, n, dedup: bool, sort_by: str):
+    """The host half of ``from_numpy_edges``: checked, symmetrized,
+    optionally deduplicated and sorted numpy ``(src, dst, w, n)``."""
     u = np.asarray(u, dtype=np.int64)
     v = np.asarray(v, dtype=np.int64)
     if w is None:
@@ -226,21 +249,7 @@ def from_numpy_edges(
         order = np.lexsort((src, dst))
     else:
         order = np.lexsort((dst, src))
-    src, dst, ww = src[order], dst[order], ww[order]
-
-    g = graph_from_arrays(
-        jnp.asarray(src, dtype=jnp.int32),
-        jnp.asarray(dst, dtype=jnp.int32),
-        jnp.asarray(ww, dtype=jnp.float32),
-        n_max=n,
-        m_max=m_max,
-        n_valid=n,
-        sorted_by=sort_by,
-        validate=False,      # full validation below covers the structural one
-    )
-    if _resolve_validate(validate):
-        validate_graph(g)
-    return g
+    return src[order], dst[order], ww[order], n
 
 
 def from_numpy_edges_robust(

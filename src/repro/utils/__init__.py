@@ -1,7 +1,7 @@
 """Shared utilities: timing, logging, registries, pytree helpers."""
-from repro.utils.timing import Timer, timed, format_seconds
+from repro.utils.timing import Timer
 from repro.utils.registry import Registry
 from repro.utils.logging import get_logger
 from repro.utils import tree
 
-__all__ = ["Timer", "timed", "format_seconds", "Registry", "get_logger", "tree"]
+__all__ = ["Timer", "Registry", "get_logger", "tree"]

@@ -1,4 +1,4 @@
-"""Process-local telemetry counters for the robustness guard rails.
+"""Process-local telemetry: counters, host-side samples and trace spans.
 
 Counters are bumped at HOST/trace time (guard activations, fallback
 engagements, fault injections) — never inside a compiled program — so they
@@ -11,11 +11,20 @@ was disabled for this capacity").
 >>> telemetry.bump("agg.pack_disabled")
 >>> telemetry.get("agg.pack_disabled")
 1
+
+``span`` is the one helper for host spans: a ``jax.profiler``
+``TraceAnnotation`` that lands in the profiler's own trace beside the device
+operations, on the same clock, and costs a no-op when no trace is being
+captured.  Device-side phases are named with ``jax.named_scope`` where the
+work is traced (``repro.local_move``, ``repro.aggregate``, …); the scope
+becomes part of every operation's name stack (its ``tf_op``) in the trace.
 """
 from __future__ import annotations
 
 import threading
 from typing import Dict
+
+import jax
 
 _lock = threading.Lock()
 _counters: Dict[str, int] = {}
@@ -54,6 +63,12 @@ def observe(name: str, value: float) -> None:
 def values() -> Dict[str, Dict[str, float]]:
     with _lock:
         return {k: dict(v) for k, v in _values.items()}
+
+
+def span(name: str, **args):
+    """A host span ``name`` in the profiler's trace, with ``args`` as its
+    stats (free when no trace is being captured)."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def snapshot() -> Dict[str, int]:

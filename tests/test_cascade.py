@@ -21,6 +21,7 @@ from repro.core.louvain import (LouvainConfig, auto_capacity_schedule,
                                 leiden, louvain)
 from repro.graph.builders import from_numpy_edges
 from repro.graph.generators import sbm
+from repro.utils import telemetry
 
 
 def _banded_graph(n=6144, band=40, k=6, seed=5):
@@ -208,19 +209,19 @@ def test_cascade_transfer_accounting():
     cfg = LouvainConfig(seed=7, backend="segment", track_modularity=False)
     louvain(g, cfg)  # warm (compile outside the counted window)
 
-    before_rb = louvain_mod._transfer_count
-    before_sync = louvain_mod._stage_sync_count
+    before_rb = telemetry.get("louvain.readback")
+    before_sync = telemetry.get("louvain.stage_sync")
     r = louvain(g, cfg)
-    assert louvain_mod._transfer_count == before_rb + 1
-    syncs = louvain_mod._stage_sync_count - before_sync
+    assert telemetry.get("louvain.readback") == before_rb + 1
+    syncs = telemetry.get("louvain.stage_sync") - before_sync
     assert 1 <= syncs <= len(auto_capacity_schedule(g.n_max, g.m_max))
     assert len(r.cascade_stages) >= 2
 
     # degenerate schedule: single program, zero stage syncs
     r0 = louvain(g, cfg.replace(capacity_schedule="none"))
-    before_sync = louvain_mod._stage_sync_count
+    before_sync = telemetry.get("louvain.stage_sync")
     louvain(g, cfg.replace(capacity_schedule="none"))
-    assert louvain_mod._stage_sync_count == before_sync
+    assert telemetry.get("louvain.stage_sync") == before_sync
     _assert_bitwise_equal(r, r0)
 
 

@@ -21,6 +21,7 @@ import importlib
 louvain_mod = importlib.import_module("repro.core.louvain")
 from repro.graph.builders import from_numpy_edges
 from repro.graph.generators import ring_of_cliques, sbm
+from repro.utils import telemetry
 
 
 def _graph(seed=7, n=200, k=5):
@@ -102,9 +103,9 @@ def test_pipeline_single_readback():
 
 def test_pipeline_transfer_counter_hook():
     g = _graph(seed=6)
-    before = louvain_mod._transfer_count
+    before = telemetry.get("louvain.readback")
     louvain(g, LouvainConfig(seed=6))
-    assert louvain_mod._transfer_count == before + 1
+    assert telemetry.get("louvain.readback") == before + 1
 
 
 def test_max_levels_one_regression():
