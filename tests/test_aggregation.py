@@ -15,6 +15,7 @@ louvain/leiden runs under ``aggregation="binned"`` vs ``"sort"``.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import aggregation
@@ -290,6 +291,182 @@ def test_groupby_sum_all_invalid():
         valid=jnp.zeros((m,), bool))
     assert int(ng) == 0
     assert not bool(np.asarray(gv).any())
+
+
+# ------------------------------------------------------------ sort_by_keys
+
+I32_MAX = np.iinfo(np.int32).max
+
+
+def _lsd_sort_by_keys(keys, values=()):
+    """The earlier ``sort_by_keys``: one (key, position) sort per key, least
+    significant first, the permutation applied by gathers.  Kept here only
+    as the reference the one-sort version must equal bit for bit."""
+    pos = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    perm = pos
+    for k in reversed(tuple(keys)):
+        _, order = jax.lax.sort((k[perm], pos), num_keys=2, is_stable=False)
+        perm = perm[order]
+    return tuple(k[perm] for k in keys), tuple(v[perm] for v in values)
+
+
+def _flag_key_groupby_sum(keys, values, valid=None):
+    """The earlier ``groupby_sum``: validity as a leading sort key, over
+    ``_lsd_sort_by_keys``; the reference for the folded-validity version."""
+    m = values.shape[0]
+    if valid is None:
+        valid = jnp.ones((m,), dtype=bool)
+    flag = jnp.where(valid, 0, 1).astype(jnp.int32)
+    (sk, sv) = _lsd_sort_by_keys((flag,) + tuple(keys), (values,))
+    sflag, *skeys = sk
+    svalid = sflag == 0
+    starts_all = seg.run_starts(sflag, *skeys)
+    starts = starts_all & svalid
+    rid = seg.run_ids(starts_all)
+    sums = jax.ops.segment_sum(jnp.where(svalid, sv[0], 0.0), rid,
+                               num_segments=m)
+    n_groups = jnp.sum(starts.astype(jnp.int32))
+    group_valid = jnp.arange(m, dtype=jnp.int32) < n_groups
+    pos = jnp.where(starts, rid, m)
+    idx = (jnp.zeros((m + 1,), jnp.int32)
+           .at[pos].set(jnp.arange(m, dtype=jnp.int32), mode="drop")[:m])
+    return tuple(k[idx] for k in skeys), sums, group_valid, n_groups
+
+
+def _sort_case(case, rng):
+    """int32 key columns for one ``sort_by_keys`` case."""
+    if case == "one_key":
+        return [rng.integers(0, 50, 300)]
+    if case == "two_keys":
+        return [rng.integers(0, 40, 300), rng.integers(0, 40, 300)]
+    if case == "three_keys":
+        return [rng.integers(-20, 20, 400) for _ in range(3)]
+    if case == "heavy_ties":
+        return [rng.integers(0, 3, 500) for _ in range(3)]
+    if case == "sentinel_and_int32_max":
+        n = 64     # the sentinel of a 64-vertex graph
+        pool = np.array([0, 1, n - 1, n, I32_MAX - 1, I32_MAX])
+        return [rng.choice(pool, 300), rng.choice(pool, 300)]
+    if case == "length_one":
+        return [np.array([I32_MAX]), np.array([3])]
+    if case == "all_equal":
+        return [np.full(200, 7) for _ in range(3)]
+    raise ValueError(case)
+
+
+SORT_CASES = ["one_key", "two_keys", "three_keys", "heavy_ties",
+              "sentinel_and_int32_max", "length_one", "all_equal"]
+
+
+@pytest.mark.parametrize("n_values", [1, 2])
+@pytest.mark.parametrize("case", SORT_CASES)
+def test_sort_by_keys_matches_numpy_lexsort(case, n_values):
+    """The same permutation as numpy's stable ``lexsort``, with the keys
+    sorted and every value carried bit for bit (float32 values include NaN
+    payloads and -0.0, which only an untouched move keeps)."""
+    rng = np.random.default_rng(SORT_CASES.index(case))
+    keys = [k.astype(np.int32) for k in _sort_case(case, rng)]
+    m = keys[0].shape[0]
+    ints = rng.integers(np.iinfo(np.int32).min, I32_MAX, m, dtype=np.int64)
+    floats = rng.standard_normal(m).astype(np.float32)
+    floats[::7] = -0.0
+    floats.view(np.uint32)[3::11] = 0x7FC00001 + np.arange(
+        floats[3::11].size, dtype=np.uint32)     # distinct NaN payloads
+    values = [ints.astype(np.int32), floats][:n_values]
+
+    sk, sv = seg.sort_by_keys([jnp.asarray(k) for k in keys],
+                              [jnp.asarray(v) for v in values])
+    perm = np.lexsort(keys[::-1])     # numpy: last key is the primary one
+    assert len(sk) == len(keys) and len(sv) == n_values
+    for got, k in zip(sk, keys):
+        np.testing.assert_array_equal(np.asarray(got), k[perm])
+    for got, v in zip(sv, values):
+        assert np.asarray(got).dtype == v.dtype
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      v[perm].view(np.uint32))
+    # the order the earlier per-key passes gave, too
+    lk, lv = _lsd_sort_by_keys([jnp.asarray(k) for k in keys],
+                               [jnp.asarray(v) for v in values])
+    for a, b in zip(sk + sv, lk + lv):
+        np.testing.assert_array_equal(np.asarray(a).view(np.uint32),
+                                      np.asarray(b).view(np.uint32))
+
+
+@pytest.mark.parametrize("with_valid", [False, True])
+def test_groupby_sum_folded_validity_matches_numpy(with_valid):
+    """Invalid entries hold ordinary (non-sentinel) keys; their groups must
+    vanish, the valid groups come out in key order with their sums, and
+    the valid prefix equals the earlier flag-key GroupBy bit for bit."""
+    rng = np.random.default_rng(11)
+    m = 400
+    k1 = rng.integers(0, 9, m).astype(np.int32)
+    k2 = rng.integers(0, 5, m).astype(np.int32)
+    vals = rng.standard_normal(m).astype(np.float32)
+    valid = rng.random(m) < 0.6 if with_valid else np.ones(m, bool)
+    jvalid = jnp.asarray(valid) if with_valid else None
+
+    (g1, g2), gs, gv, ng = seg.groupby_sum(
+        (jnp.asarray(k1), jnp.asarray(k2)), jnp.asarray(vals), valid=jvalid)
+    expect = {}
+    for a, b, x, ok in zip(k1, k2, vals, valid):
+        if ok:
+            expect[(int(a), int(b))] = expect.get((int(a), int(b)), 0.0) + float(x)
+    n = int(ng)
+    assert n == len(expect)
+    np.testing.assert_array_equal(np.asarray(gv), np.arange(m) < n)
+    got_keys = list(zip(np.asarray(g1)[:n].tolist(), np.asarray(g2)[:n].tolist()))
+    assert got_keys == sorted(expect)
+    for key, s in zip(got_keys, np.asarray(gs)[:n]):
+        assert float(s) == pytest.approx(expect[key], abs=1e-5)
+
+    (r1, r2), rs, rv, rn = _flag_key_groupby_sum(
+        (jnp.asarray(k1), jnp.asarray(k2)), jnp.asarray(vals), valid=jvalid)
+    assert int(rn) == n
+    np.testing.assert_array_equal(np.asarray(rv), np.asarray(gv))
+    np.testing.assert_array_equal(np.asarray(r1)[:n], np.asarray(g1)[:n])
+    np.testing.assert_array_equal(np.asarray(r2)[:n], np.asarray(g2)[:n])
+    np.testing.assert_array_equal(np.asarray(rs)[:n].view(np.uint32),
+                                  np.asarray(gs)[:n].view(np.uint32))
+
+
+@pytest.mark.parametrize("algorithm", ["louvain", "plp"])
+def test_whole_solve_bit_identical_to_per_key_sort(algorithm, monkeypatch):
+    """Default ``louvain()`` / ``plp()`` on an R-MAT graph give the same
+    labels, sweeps and modularity with the one-sort GroupBy as with the
+    earlier per-key-pass sort and flag-key GroupBy patched in."""
+    from repro.core import progcache
+    from repro.core.louvain import louvain
+    from repro.core.plp import plp
+    from repro.graph.generators import rmat
+
+    graphs = [from_numpy_edges(*rmat(10, 3, seed=s), n=1 << 10)
+              for s in (0, 1)]
+
+    def solve_all():
+        progcache.clear_caches()
+        jax.clear_caches()
+        out = []
+        for g in graphs:
+            if algorithm == "louvain":
+                r = louvain(g)
+                out.append((np.asarray(r.labels), list(r.sweeps_per_level),
+                            float(r.modularity), list(r.modularity_history)))
+            else:
+                r = plp(g)
+                out.append((np.asarray(r.labels), r.iterations,
+                            list(r.delta_n_history), None))
+        return out
+
+    new = solve_all()
+    monkeypatch.setattr(seg, "sort_by_keys", _lsd_sort_by_keys)
+    monkeypatch.setattr(seg, "groupby_sum", _flag_key_groupby_sum)
+    old = solve_all()
+    monkeypatch.undo()
+    progcache.clear_caches()
+    jax.clear_caches()
+    for (la, *ra), (lb, *rb) in zip(new, old):
+        np.testing.assert_array_equal(la, lb)
+        assert ra == rb
 
 
 # ------------------------------------------------------------ sort-free binned
