@@ -19,12 +19,7 @@ program's, so no limit between them holds; PERF.md gives the readings.)
 """
 from __future__ import annotations
 
-import importlib.util
-import os
-
 import numpy as np
-
-BENCH = os.path.dirname(os.path.abspath(__file__))
 
 
 def symmetric(u, v):
@@ -34,16 +29,6 @@ def symmetric(u, v):
     v = np.asarray(v, np.int64)
     return (np.concatenate([u, v]), np.concatenate([v, u]),
             np.ones(2 * u.size))
-
-
-def reference(algorithm: str, base: str = BENCH):
-    """The plain reference module ``reference/<algorithm>.py``."""
-    path = os.path.join(base, "reference", f"{algorithm}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{algorithm}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def is_partition(labels, n: int, n_communities) -> bool:

@@ -71,9 +71,9 @@ def control_readings(bench: dict, name: str, seed: int,
     cell = spec.workload(bench, name)
     cfg = spec.config(bench, cell["config"], root)
     traffic = spec.traffic(cell["traffic"], base)
-    gs = graphs.closed_order(cfg, traffic, seed)
-    gs.append(graphs.check_graph(cfg, seed))
-    ref = check.reference(traffic["algorithm"], base)
+    gs = graphs.closed_order(cfg, traffic, seed, base)
+    gs.append(graphs.check_graph(cfg, seed, base))
+    ref = spec.reference(traffic["algorithm"], base)
     judge = check.Judge(ref, gs)
     answers = []
     for gi, (u, v, n) in enumerate(gs):
@@ -104,7 +104,7 @@ def main(argv=None) -> int:
         print(f"FAIL: {err}", file=sys.stderr)
         return 2
     traffic = spec.traffic(spec.workload(bench, a.workload)["traffic"])
-    ref = check.reference(traffic["algorithm"])
+    ref = spec.reference(traffic["algorithm"])
     for seed in a.program_seeds:
         seen = {}
 

@@ -1,9 +1,10 @@
 """The loop that drives the system: whole solves by one caller, back to
 back, in whole rounds of the cell's graphs.
 
-It calls the program only through its public entry points
-(``from_numpy_edges``, then ``louvain()`` or ``plp()``) and times them on
-the host clock; each step sits in a ``bench.*`` span for the trace.
+It calls the program only through the cell's entry (``entries/<call>.py``:
+``ingest``, such as ``from_numpy_edges``, then ``solve``, such as
+``louvain()``) and times them on the host clock; each step sits in a
+``bench.*`` span for the trace.
 """
 from __future__ import annotations
 
@@ -39,24 +40,19 @@ class Solve:
     answer: Answer
 
 
-def solve_once(algorithm: str, graph: int, u, v, n: int):
-    """One whole solve: host edge list → ``from_numpy_edges`` → the
-    algorithm → labels on the host.  Returns ``(ingest_s, Answer)``."""
-    from repro.core.louvain import louvain
-    from repro.core.plp import plp
-    from repro.graph.builders import from_numpy_edges
-
-    entry = {"louvain": louvain, "plp": plp}[algorithm]
+def solve_once(entry, graph: int, u, v, n: int):
+    """One whole solve: host edge list → ``entry.ingest`` → ``entry.solve``
+    → labels on the host.  Returns ``(ingest_s, Answer)``."""
     t0 = time.perf_counter()
     with span("bench.ingest"):
-        g = from_numpy_edges(u, v, n=n)
+        g = entry.ingest(u, v, n)
     t1 = time.perf_counter()
     with span("bench.solve"):
-        ans = answer_of(graph, entry(g))
+        ans = answer_of(graph, entry.solve(g))
     return t1 - t0, ans
 
 
-def closed(graphs, algorithm: str, seconds: float) -> list:
+def closed(graphs, entry, seconds: float) -> list:
     """Solve ``graphs`` in turn, back to back, in whole rounds (each graph
     once), until a round ends at or after ``seconds``: at any speed every
     graph is solved equally often, so every seed does the same work."""
@@ -64,7 +60,7 @@ def closed(graphs, algorithm: str, seconds: float) -> list:
     t0 = time.perf_counter()
     while True:
         for gi, g in enumerate(graphs):
-            ingest_s, ans = solve_once(algorithm, gi, *g)
+            ingest_s, ans = solve_once(entry, gi, *g)
             out.append(Solve(time.perf_counter() - t0, ingest_s, ans))
         if out[-1].end >= seconds:
             return out

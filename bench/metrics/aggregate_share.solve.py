@@ -1,9 +1,7 @@
 """Device time of aggregation (operations in the program's
 ``repro.aggregate`` scope) over device busy time in the window; see
-``bench/scopes.py``."""
-from bench import scopes
+``bench/trace.py``."""
 
 
 def read(run):
-    s = scopes.of_run(run, scopes.checkout_of(__file__))
-    return s.scope_share("repro.aggregate") if s else None
+    return run.summary.scope_share("repro.aggregate") if run.summary else None

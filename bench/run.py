@@ -42,7 +42,7 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from bench import check, device, graphs, loops, spec, stats  # noqa: E402
+from bench import check, device, spec  # noqa: E402
 from bench import trace as tracing  # noqa: E402
 
 # inside the checkout, at fixed paths: the cache directory is part of
@@ -55,39 +55,16 @@ def log(*a) -> None:
     print(*a, file=sys.stderr, flush=True)
 
 
+def _top(seconds: dict, n: int | None = None) -> list:
+    return sorted(seconds.items(), key=lambda kv: -kv[1])[:n]
+
+
 @dataclasses.dataclass
 class Run:
     """What a per-layer reader (``metrics/<name>.py``) may read."""
 
     solves: list | None          # [loops.Solve] in the window
     summary: tracing.Summary     # the traced window
-
-
-# ------------------------------------------------------------ cells
-
-
-def closed_cell(cfg, traffic, seed, seconds, window):
-    """Closed loop: one caller, whole rounds of whole solves back to back;
-    then one graph drawn from the seed, solved the same way and judged
-    with the rest."""
-    gs = graphs.closed_order(cfg, traffic, seed)
-    loops.solve_once(traffic["algorithm"], 0, *gs[0])       # warm-up
-    with window():
-        solves = loops.closed(gs, traffic["algorithm"], seconds)
-    win, n = stats.solve_window([s.end for s in solves], seconds, len(gs))
-    ends = [0.0] + [s.end for s in solves]
-    log(f"solves in window: {n} over {win!r} s; each (s): "
-        f"{[b - a for a, b in zip(ends, ends[1:])]}")
-    gs.append(graphs.check_graph(cfg, seed))
-    _, seeded = loops.solve_once(traffic["algorithm"], len(gs) - 1, *gs[-1])
-    log(f"graph drawn from the seed: solved after the window, "
-        f"{seeded.n_communities} communities, modularity {seeded.modularity!r}")
-    return dict(graphs=gs, answers=[s.answer for s in solves] + [seeded],
-                attempted=n, failed=0, unanswered=0,
-                metrics={"solve_s": win / n}, solves=solves[:n])
-
-
-CELLS = {"closed": closed_cell}
 
 
 # ------------------------------------------------------------ one run
@@ -137,7 +114,8 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
         marks["window"] = counter.snapshot()
 
     try:
-        out = CELLS[traffic["loop"]](cfg, traffic, seed, seconds, window)
+        out = spec.loop(traffic["loop"], base)(cfg, traffic, seed, seconds,
+                                               window, log)
     finally:
         counter.close()
     setup_s = marks["t0"] - t_start
@@ -161,11 +139,13 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
         values = {m["name"]: (spec.reader(m["name"], base)(run), m["unit"])
                   for m in spec.metrics_of(bench, name, "per_layer")}
         result["breakdown"] = tracing.breakdown(run.summary)
-        log(f"trace: {trace_file[0][0]}; busy_s={run.summary.busy_s!r} "
-            f"window_s={run.summary.window_s!r} devices="
-            f"{run.summary.devices}; device time by class: "
-            f"{sorted(run.summary.by_class.items(), key=lambda kv: -kv[1])[:12]}")
-        del run
+        s = run.summary
+        log(f"trace: {trace_file[0][0]}; busy_s={s.busy_s!r} "
+            f"window_s={s.window_s!r} devices={s.devices}; device time by "
+            f"class (s): {_top(s.by_class, 12)}; by scope (s): "
+            f"{_top(s.by_scope)}; repro.* spans in the window (s): "
+            f"{_top(s.span_s)}; idle time by span (s): {s.gaps[:10]}")
+        del run, s
     else:
         values = {"setup_s": (setup_s, "s")}
         for m in spec.metrics_of(bench, name, "end_to_end"):
@@ -178,7 +158,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
 
     if on_answers:
         on_answers(out["graphs"], out["answers"])
-    judge = check.Judge(check.reference(traffic["algorithm"], base),
+    judge = check.Judge(spec.reference(traffic["algorithm"], base),
                         out["graphs"])
     for a in out["answers"]:
         judge.judge(a.graph, a.labels, a.modularity, a.n_communities)

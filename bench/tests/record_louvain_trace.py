@@ -1,14 +1,15 @@
 #!/usr/bin/env python3
-"""Record the small TPU trace that ``test_bench_scopes.py`` reads.
+"""Record the small TPU trace that ``test_bench_trace.py`` reads.
 
     python3 bench/tests/record_louvain_trace.py <out.xplane.pb> [scale]
 
-On the chip it is started on: two R-MAT graphs (``bench/graphs.rmat_edges``,
-default scale 11, edge factor 3) are solved once each to compile, then
-solved again through the harness's own loop (``loops.solve_once``: host edge
-list, ``from_numpy_edges``, default ``louvain()``, labels on the host),
-inside a ``bench.window`` span, under the profiler.  The recorded trace is
-cut to what ``bench/trace.py`` and ``bench/scopes.py`` read (``trim``), so
+On the chip it is started on: two R-MAT graphs
+(``bench/generators/rmat.rmat_edges``, default scale 11, edge factor 3)
+are solved once each to compile, then solved again through the harness's
+own loop (``loops.solve_once`` with ``entries/louvain.py``: host edge list,
+``from_numpy_edges``, default ``louvain()``, labels on the host), inside a
+``bench.window`` span, under the profiler.  The recorded trace is cut to
+what ``bench/trace.py`` reads (``trim``), so
 the file stays under 1 MB: each ``/device:TPU:<n>`` plane's ``XLA Ops``
 line, with its events' times and its operations' names and ``tf_op``, and
 the ``/host:CPU`` lines that hold the spans.
@@ -28,7 +29,7 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from bench import graphs, loops, scopes, trace  # noqa: E402
+from bench import graphs, loops, spec, trace  # noqa: E402
 
 KEEP = ("/device:TPU:", "/host:CPU")
 
@@ -51,12 +52,12 @@ def _raw(b: bytes, lo: int, hi: int):
     i = lo
     while i < hi:
         start = i
-        key, i = scopes._varint(b, i)
+        key, i = trace._varint(b, i)
         f, wire = key >> 3, key & 7
         if wire == 0:
-            v, i = scopes._varint(b, i)
+            v, i = trace._varint(b, i)
         elif wire == 2:
-            n, i = scopes._varint(b, i)
+            n, i = trace._varint(b, i)
             v, i = (i, i + n), i + n
         else:
             v, i = None, i + (8 if wire == 1 else 4)
@@ -70,11 +71,11 @@ def _sub(field: int, body: bytes) -> bytes:
 def _device_plane(b: bytes, lo: int, hi: int) -> bytes:
     """A device plane with its ``XLA Ops`` line alone, its events without
     stats, and its event metadata down to id, name and ``tf_op``."""
-    stat_names = scopes._plane(b, lo, hi)["stats"]
+    stat_names = trace._plane(b, lo, hi)["stats"]
     out = bytearray()
     for f, v, raw in _raw(b, lo, hi):
         if f == 3:
-            if scopes._line(b, v)[0] != "XLA Ops":
+            if trace._line(b, v)[0] != "XLA Ops":
                 continue
             line = bytearray()
             for lf, lv, lraw in _raw(b, *v):
@@ -89,7 +90,7 @@ def _device_plane(b: bytes, lo: int, hi: int) -> bytes:
                 if ef == 2:
                     eraw = _sub(2, b"".join(
                         r for mf, mv, r in _raw(b, *ev)
-                        if mf in (1, 2) or (mf == 5 and scopes._stat(
+                        if mf in (1, 2) or (mf == 5 and trace._stat(
                             b, mv, stat_names)[0] == "tf_op")))
                 entry += eraw
             raw = _sub(4, bytes(entry))
@@ -98,14 +99,14 @@ def _device_plane(b: bytes, lo: int, hi: int) -> bytes:
 
 
 def trim(data: bytes) -> bytes:
-    """The ``XSpace`` ``data`` cut to what ``bench/trace.py`` and
-    ``bench/scopes.py`` read: the planes named in ``KEEP``; of a device
-    plane, what ``_device_plane`` keeps; of the host plane, the lines that
-    hold a ``bench.*`` or ``repro.*`` span."""
+    """The ``XSpace`` ``data`` cut to what ``bench/trace.py`` reads: the
+    planes named in ``KEEP``; of a device plane, what ``_device_plane``
+    keeps; of the host plane, the lines that hold a ``bench.*`` or
+    ``repro.*`` span."""
     out = bytearray()
     for f, v, raw in _raw(data, 0, len(data)):
         if f == 1:
-            plane = scopes._plane(data, *v)
+            plane = trace._plane(data, *v)
             if not plane["name"].startswith(KEEP):
                 continue
             if plane["name"].startswith("/device:"):
@@ -115,32 +116,34 @@ def trim(data: bytes) -> bytes:
                 raw = _sub(1, b"".join(
                     r for pf, pv, r in _raw(data, *v)
                     if pf != 3 or any(
-                        names.get(scopes._event(data, e)[0], "").startswith(
-                            scopes.HOST_PREFIXES)
-                        for e in scopes._line(data, pv)[2])))
+                        names.get(trace._event(data, e)[0], "").startswith(
+                            trace.HOST_PREFIXES)
+                        for e in trace._line(data, pv)[2])))
         out += raw
     return bytes(out)
 
 
 def main(out: str, scale: int = 11) -> None:
+    rmat_edges = spec.module("generators", "rmat").rmat_edges
     gs = []
     for k in range(2):
-        lo, hi = graphs.rmat_edges(scale, 3, 0.57, 0.19, 0.19,
-                                   graphs.stream(0, 1, k))
+        lo, hi = rmat_edges(scale, 3, 0.57, 0.19, 0.19,
+                            graphs.stream(0, 1, k))
         gs.append((lo, hi, 1 << scale))
+    entry = spec.entry({"algorithm": "louvain"})
     for k, g in enumerate(gs):
-        loops.solve_once("louvain", k, *g)
+        loops.solve_once(entry, k, *g)
     tmp = out + ".d"
     with trace.capture(tmp) as found:
         with trace.span(trace.WINDOW):
             for k, g in enumerate(gs):
-                loops.solve_once("louvain", k, *g)
+                loops.solve_once(entry, k, *g)
     with open(found[0], "rb") as f:
         data = trim(f.read())
     with open(out, "wb") as f:
         f.write(data)
     shutil.rmtree(tmp, ignore_errors=True)
-    s = scopes.summarize(scopes.load(out))
+    s = trace.summarize(trace.load(out))
     print(f"{out}: {len(data)} bytes; window {s.window_s!r} s, busy "
           f"{s.busy_s!r} s; by scope {s.by_scope}; spans {s.span_s}; "
           f"gaps {s.gaps}")

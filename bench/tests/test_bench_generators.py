@@ -1,12 +1,13 @@
 """The benchmark's own generators: deterministic by seed, and of the sizes
 their configuration states."""
+import hashlib
 import json
 import os
 
 import numpy as np
 import pytest
 
-from bench import graphs
+from bench import graphs, spec
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -40,7 +41,7 @@ def test_rmat_is_deterministic_by_seed(youtube):
     a = graphs.check_graph(youtube, 7)
     b = graphs.check_graph(youtube, 7)
     c = graphs.check_graph(youtube, 8)
-    d = graphs.rmat_graph(youtube, graphs.stream(7, 1))
+    d = spec.generator("rmat")(youtube, graphs.stream(7, 1))
     assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
     assert not np.array_equal(a[1], c[1])
     assert not np.array_equal(a[1], d[1])
@@ -74,3 +75,33 @@ def test_rmat_keeps_the_graph500_skew(youtube):
     # heavy tail: the top 1% of vertices hold far more than 1% of degree
     top = np.sort(deg)[-n // 100:].sum() / deg.sum()
     assert top > 0.1
+
+
+def _sha(g) -> str:
+    u, v, n = g
+    h = hashlib.sha256()
+    for part in (u.astype(np.int64).tobytes(), v.astype(np.int64).tobytes(),
+                 str(n).encode()):
+        h.update(part)
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("seed, timed, check", [
+    (0, ["fef39a269a5fbc2a", "f34e4110c8ae33b3"], "4f19951798ad5c70"),
+    (2900000011, ["f34e4110c8ae33b3", "fef39a269a5fbc2a"], "2af6892398dfb4f4"),
+])
+def test_youtube_cell_solves_the_graphs_it_always_has(youtube, seed, timed,
+                                                      check):
+    """The cell's inputs, pinned: the timed graphs in the seed's order and
+    the graph drawn from the seed, as sha256 over ``u``, ``v`` (int64) and
+    ``n``, as the harness made them before generators were found by
+    ``kind``."""
+    traffic = spec.traffic("louvain")
+    assert [_sha(g) for g in graphs.closed_order(youtube, traffic, seed)] \
+        == timed
+    assert _sha(graphs.check_graph(youtube, seed)) == check
+
+
+def test_unknown_kind_names_the_file_it_looked_for(youtube):
+    with pytest.raises(FileNotFoundError, match="generators/nothing.py"):
+        graphs.check_graph(dict(youtube, kind="nothing"), 1)
