@@ -9,7 +9,9 @@ shape:
     submit() → admission control (bounded depth + estimated-cost budget,
                typed ``OverloadError`` sheds) → canonical ingest (per
                request, so a poisoned edge list is rejected/repaired BEFORE
-               it can share a batch with clean traffic) → queue
+               it can share a batch with clean traffic), padded on the host
+               to the bucket of its canonical sizes (``ingest_request``)
+               → queue
     flush()  → group by (algo, capacity signature) → ``louvain_batch`` /
                ``plp_batch`` dispatch per group → unpack per-request
                responses with the PR-7 ``RunReport`` and wall-clock latency
@@ -47,6 +49,14 @@ that ONE group to sequential single-graph runs so clean requests still get
 answers and only the offending request carries the error — recorded in
 ``stats()["counters"]`` as ``serve.batch_fallback_sequential``.
 
+Ingest runs on the host alone: each request is canonicalized, validated
+and padded in numpy to its bucket's capacities, and a batch moves to the
+device in one transfer (``core.batch``), so once each signature's batch
+program exists a flush traces and compiles nothing, whatever the
+requests' edge counts.  Host spans ``repro.serve.submit`` and
+``repro.serve.flush`` (around the batch engine's ``repro.batch.*`` spans)
+name where a served window's time goes in the profiler's trace.
+
 Deliberately synchronous and in-process: flush() is the unit a real
 transport (thread, asyncio loop, RPC server) would call on its batching
 tick; the engine itself stays free of I/O so it can be tested and
@@ -60,13 +70,16 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.batch import louvain_batch, plp_batch
 from repro.core.louvain import LouvainConfig, louvain
 from repro.core.plp import PLPConfig, plp
 from repro.core import progcache
-from repro.graph.builders import from_numpy_edges_robust
+from repro.graph import packing
+from repro.graph.builders import RepairReport, from_numpy_edges_robust
+from repro.graph.structure import Graph
 from repro.kernels.common import capacity_signature
 from repro.utils import faultinject, resilience, telemetry
 from repro.utils.errors import (CommunityDetectionError, DeadlineError,
@@ -128,6 +141,22 @@ def _estimate_cost(req: CommunityRequest) -> int:
         n_est = 1
     sig = capacity_signature(max(n_est, 1), max(m_est, 1))
     return int(sig.n_cap) + int(sig.m_cap)
+
+
+def ingest_request(req: CommunityRequest,
+                   **ingest) -> Tuple[Graph, RepairReport]:
+    """One request's canonical ingest on the host
+    (``from_numpy_edges_robust`` with the options ``ingest``), padded to
+    the capacity bucket of its CANONICAL sizes, so the batch path stacks
+    it as it is.  A request that lists both directions of an edge, or an
+    edge twice, lands in the bucket of its one-sided form.  An empty
+    request keeps zero capacity (the trivial result, no batch slot)."""
+    g, rep = from_numpy_edges_robust(req.u, req.v, req.w, n=req.n,
+                                     device=False, **ingest)
+    if g.n_max:
+        sig = capacity_signature(g.n_max, g.m_max)
+        g = packing.pad_graph(g, sig.n_cap, sig.m_cap)
+    return g, rep
 
 
 def _fail(q: _Queued, err_text: str, batch: int,
@@ -207,8 +236,11 @@ class CommunityServeEngine:
         """
         if req.algo not in ALGOS:
             raise ValueError(f"unknown algo {req.algo!r}; choose {ALGOS}")
-        t0 = time.perf_counter()
+        with telemetry.span("repro.serve.submit"):
+            return self._submit(req, time.perf_counter())
 
+    def _submit(self, req: CommunityRequest,
+                t0: float) -> Optional[CommunityResponse]:
         if len(self._queue) >= self.max_queue_depth:
             return self._shed_response(req, t0, (
                 f"queue depth {len(self._queue)} at bound "
@@ -224,8 +256,7 @@ class CommunityServeEngine:
         deadline = (resilience.Deadline(req.deadline_ms / 1000.0)
                     if req.deadline_ms is not None else None)
         try:
-            g, rep = from_numpy_edges_robust(req.u, req.v, req.w, n=req.n,
-                                             **self.ingest)
+            g, rep = ingest_request(req, **self.ingest)
         except CommunityDetectionError as err:
             telemetry.bump("serve.ingest_reject")
             self._rejects.append((self._seq, CommunityResponse(
@@ -272,6 +303,10 @@ class CommunityServeEngine:
 
     def flush(self) -> List[CommunityResponse]:
         """Serve everything queued; responses in submit order."""
+        with telemetry.span("repro.serve.flush"):
+            return self._flush()
+
+    def _flush(self) -> List[CommunityResponse]:
         queue, self._queue = self._queue, []
         self._queue_cost = 0
         rejects, self._rejects = self._rejects, []
@@ -404,7 +439,8 @@ class CommunityServeEngine:
                       if q.deadline is not None else None)
             try:
                 results.append(resilience.call_with_deadline(
-                    lambda g=q.graph: single(g, cfg), budget))
+                    lambda g=q.graph: single(jax.device_put(g), cfg),
+                    budget))
             except CommunityDetectionError as err:
                 results.append(err)
         return results
@@ -414,6 +450,8 @@ class CommunityServeEngine:
             kind = type(res).__name__
             return _fail(q, f"{kind}: {res}", batch,
                          warning=f"serve:{kind}:{q.req.request_id}")
+        # the graph sits at its bucket's capacity: keep its own vertices
+        res = dataclasses.replace(res, labels=res.labels[:int(q.graph.n_valid)])
         now = time.perf_counter()
         sig = (tuple(capacity_signature(q.graph.n_max, q.graph.m_max))
                if q.graph.n_max else None)

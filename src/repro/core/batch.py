@@ -12,9 +12,12 @@ launch + readback latency as a 300k-vertex one.  ``louvain_batch`` /
      (padding waste bounded <2×), so arbitrarily-sized traffic lands on a
      handful of buckets;
   2. **pack** each bucket along a new leading batch axis
-     (``graph.packing``): capacity-padded arrays stack for free, the batch
-     is padded to a power-of-two slot count with fully-masked empty-slot
-     graphs so steady-state traffic reuses a handful of compiled shapes;
+     (``graph.packing``), on the host: capacity-padded arrays stack for
+     free, every dispatch of a signature has the same slot count
+     (``dispatch_width``: ``max_slots``, fewer for wide signatures; short
+     chunks are filled with fully-masked empty-slot graphs), and the batch
+     moves to the device in one ``device_put`` — so each signature has ONE
+     compiled program and no graph size or chunk size traces anything;
   3. **dispatch** the existing fused stage program under ``jax.vmap``: the
      same ``louvain._build_stage`` closure the single-graph cascade jits is
      lifted over the batch axis, so ONE dispatch serves up to ``max_slots``
@@ -35,9 +38,15 @@ Graphs without the ``sorted_by == "src"`` invariant fall back to the
 segment evaluator (also bit-identical).
 
 Compiled programs are memoized in a bounded LRU keyed on the capacity
-signature (``progcache.program_cache``), mirroring the cascade's
-≤4-stage-program discipline: steady-state traffic incurs ZERO recompiles
-(asserted by the ``batch_serve`` benchmark).
+signature and slot count (``progcache.program_cache``), mirroring the
+cascade's ≤4-stage-program discipline: steady-state traffic incurs ZERO
+recompiles (tests/test_batch.py).
+
+Host spans name each step of a dispatch in the profiler's trace:
+``repro.batch.pack`` (pad, stack, transfer), ``repro.batch.dispatch``
+(args ``n_cap``, ``m_cap``, ``slots``, ``graphs``), ``repro.batch.readback``
+and ``repro.batch.unpack``; counters ``batch.lanes`` and
+``batch.empty_lanes`` count lanes dispatched and filler lanes among them.
 
 Per-graph ``RunReport`` discipline (DESIGN.md §Robustness) is preserved:
 empty (zero-capacity) inputs short-circuit to the PR-7 trivial result
@@ -48,7 +57,6 @@ precision warnings are recorded per slot.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -100,19 +108,6 @@ def _dispatch_guarded(run, deadline: Optional[resilience.Deadline]):
         attempt, deadline.remaining_s() if deadline is not None else None)
 
 
-def pick_batch_slots(n_graphs: int) -> int:
-    """Pad the batch to the next power of two (min 1).
-
-    Slot counts are jit shape inputs: quantizing them bounds the compiled
-    programs per signature to log2(max batch) instead of one per distinct
-    request-group size.  Padding slots are fully-masked empty graphs — inert
-    vmap lanes (``graph.packing.empty_slot``).
-    """
-    if n_graphs < 1:
-        raise ValueError(f"need at least one graph, got {n_graphs}")
-    return 1 << (n_graphs - 1).bit_length()
-
-
 def _resolve_batch_backend(backend: str, sorted_ok: bool) -> str:
     """Static backend resolution for the batched path (module docstring):
     ``pallas`` → ``ell`` (vmap-of-ref fallback), and ``ell`` → ``segment``
@@ -131,17 +126,16 @@ def _resolve_batch_backend(backend: str, sorted_ok: bool) -> str:
 
 
 @program_cache("batch.louvain", maxsize=32)
-def _louvain_batch_fn(sig: CapacitySignature, spec0: EngineSpec,
+def _louvain_batch_fn(sig: CapacitySignature, slots: int, spec0: EngineSpec,
                       spec_coarse: EngineSpec,
                       refine_spec: Optional[EngineSpec], max_levels: int,
                       track_modularity: bool, agg_method: str,
                       faults: frozenset, promote: bool):
-    """One compiled batch program per capacity signature (and spec set):
-    the single-capacity whole-run stage (``_build_stage`` with
-    ``next_caps=None`` — the cascade's parity oracle) lifted through
-    ``jax.vmap`` over the leading batch axis.  ``sig`` pins the static
-    shapes in the cache key; the jit beneath retraces only when the slot
-    count changes (bounded by ``pick_batch_slots``)."""
+    """One compiled batch program per capacity signature and slot count
+    (and spec set): the single-capacity whole-run stage (``_build_stage``
+    with ``next_caps=None`` — the cascade's parity oracle) lifted through
+    ``jax.vmap`` over the leading batch axis.  ``sig`` and ``slots`` pin
+    the static shapes in the cache key, so the jit beneath traces once."""
     stage = _build_stage(
         spec0, spec_coarse, refine_spec, max_levels, track_modularity,
         None, agg_method, faults, promote)
@@ -192,15 +186,29 @@ def _unpack_labels(final_assign: np.ndarray, g: Graph, n_cap: int) -> np.ndarray
     return lab
 
 
-#: Default dispatch-width bound.  A vmapped while_loop runs every lane
-#: until the SLOWEST lane converges, so unbounded batches pay worst-case
-#: sweep/level counts for all slots; chunking a bucket into ≤MAX_SLOTS
-#: dispatches caps that lockstep waste (and the packed-batch memory
-#: footprint) while chunks of one size share one compiled program.
-#: 8 is the measured CPU-serving optimum for both drivers (the sweep in
-#: BENCH_batch_serve.json's PR notes); raise it on accelerators with
-#: parallel lanes to spare.
+#: Most lanes a dispatch runs.  A vmapped while_loop runs every lane until
+#: the SLOWEST lane converges, so unbounded batches pay worst-case
+#: sweep/level counts for all slots; chunking a bucket into at most this
+#: many lanes caps that lockstep waste (and the packed-batch memory
+#: footprint).  8 was the measured CPU-serving optimum.
 MAX_SLOTS = 8
+
+#: Edge entries one dispatch carries over all its lanes, at most: a
+#: signature runs ``dispatch_width`` lanes, one width per signature, so
+#: one compiled program per signature.  A lane costs what its entries cost
+#: once the arrays are large, live or empty (PERF.md: on the CPU an empty
+#: lane of the (256, 32768) signature costs 3/4 of a live one; on the v5e
+#: the youtube graph's 345,050-entry scatters and gathers run ~2.2 ms
+#: each), so wide signatures take fewer lanes and small ones keep
+#: ``MAX_SLOTS``.
+LANE_ENTRIES = 65536
+
+
+def dispatch_width(sig: CapacitySignature, max_slots: int = MAX_SLOTS) -> int:
+    """Lanes every dispatch of ``sig`` runs: ``max_slots``, fewer where
+    they would carry more than ``LANE_ENTRIES`` edge entries, at least 1.
+    Short chunks fill the width with empty lanes, which converge at once."""
+    return min(int(max_slots), max(1, LANE_ENTRIES // int(sig.m_cap)))
 
 
 def _chunks(idxs: List[int], max_slots: int):
@@ -234,7 +242,8 @@ def louvain_batch(graphs: Sequence[Graph],
                   deadline_s: Optional[float] = None,
                   lane_schedule: bool = True) -> List[LouvainResult]:
     """Run Louvain over many graphs with one dispatch per capacity bucket
-    (buckets wider than ``max_slots`` are chunked — see ``MAX_SLOTS``;
+    (buckets wider than their ``dispatch_width`` are chunked, and every
+    dispatch of a signature runs that many lanes — see ``LANE_ENTRIES``;
     ``lane_schedule`` orders lanes by predicted sweep count first — see
     ``_schedule_lanes`` — without affecting per-graph results).
 
@@ -271,17 +280,18 @@ def louvain_batch(graphs: Sequence[Graph],
 
     bad_slots: List[int] = []
     for (sig, sorted_by), idxs in buckets.items():
-        if lane_schedule and len(idxs) > max_slots:
+        width = dispatch_width(sig, max_slots)
+        if lane_schedule and len(idxs) > width:
             telemetry.bump("batch.lane_scheduled_buckets")
             idxs = _schedule_lanes(graphs, idxs)
-        for chunk in _chunks(idxs, max_slots):
+        for chunk in _chunks(idxs, width):
             if deadline is not None and deadline.expired:
                 raise DeadlineError(
                     f"batch deadline ({deadline_s:.3f}s) expired with "
                     "bucket dispatches still pending")
             bad_slots += _run_louvain_bucket(
                 graphs, chunk, sig, sorted_by, cfg, faults, active_faults,
-                results, deadline)
+                results, width, deadline)
     if bad_slots:
         raise NumericError(
             "non-finite edge weight detected inside the fused level loop "
@@ -289,9 +299,34 @@ def louvain_batch(graphs: Sequence[Graph],
     return results  # type: ignore[return-value]
 
 
+def _pack(graphs, idxs, sig: CapacitySignature, sorted_by, slots: int):
+    """The chunk ``idxs`` as one device batch of ``slots`` lanes: padded
+    to the signature and stacked on the host, filled with empty lanes, and
+    moved to the device in one ``device_put``."""
+    with telemetry.span("repro.batch.pack"):
+        lanes = [packing.pad_graph(graphs[i], sig.n_cap, sig.m_cap)
+                 for i in idxs]
+        lanes += [packing.empty_slot(sig.n_cap, sig.m_cap, sorted_by)
+                  ] * (slots - len(lanes))
+        gb = jax.device_put(packing.stack_graphs(lanes))
+    telemetry.bump("batch.lanes", slots)
+    telemetry.bump("batch.empty_lanes", slots - len(idxs))
+    return gb
+
+
+def _dispatch(fn, gb, seed: int, n_graphs: int, readback):
+    """One batch program call and its one readback, each in its span."""
+    with telemetry.span("repro.batch.dispatch", n_cap=gb.n_max,
+                        m_cap=gb.m_max, slots=int(gb.n_valid.shape[0]),
+                        graphs=n_graphs):
+        out = fn(gb, jnp.uint32(seed))
+    with telemetry.span("repro.batch.readback"):
+        return readback(out)
+
+
 def _run_louvain_bucket(graphs, idxs, sig: CapacitySignature,
                         sorted_by, cfg: LouvainConfig, faults: frozenset,
-                        active_faults, results,
+                        active_faults, results, slots: int,
                         deadline: Optional[resilience.Deadline] = None,
                         ) -> List[int]:
     timer = Timer()
@@ -301,57 +336,51 @@ def _run_louvain_bucket(graphs, idxs, sig: CapacitySignature,
     promote = accum_needs_promotion(sig.m_cap)
 
     with timer.phase("pack"):
-        padded = [packing.pad_graph(graphs[i], sig.n_cap, sig.m_cap)
-                  for i in idxs]
-        slots = pick_batch_slots(len(padded))
-        filler = packing.empty_slot(sig.n_cap, sig.m_cap)
-        if filler.sorted_by != sorted_by:
-            filler = dataclasses.replace(filler, sorted_by=sorted_by)
-        padded += [filler] * (slots - len(padded))
-        gb = packing.stack_graphs(padded)
-
-    fn = _louvain_batch_fn(sig, spec0, spec_coarse, refine_spec,
+        gb = _pack(graphs, idxs, sig, sorted_by, slots)
+    fn = _louvain_batch_fn(sig, slots, spec0, spec_coarse, refine_spec,
                            cfg.max_levels, cfg.track_modularity,
                            cfg.aggregation, faults, promote)
     with timer.phase("pipeline"):
         # ONE bulk transfer per bucket; under a deadline the dispatch +
         # readback run watchdogged (fault sites fire inside the window)
         host = _dispatch_guarded(
-            lambda: _readback(fn(gb, jnp.uint32(cfg.seed))), deadline)
+            lambda: _dispatch(fn, gb, cfg.seed, len(idxs), _readback),
+            deadline)
     (final_assign, n_final, level, q_final,
      mod_h, sw_h, nc_h, dn_h, bad_w) = host
     telemetry.bump("batch.louvain_dispatches")
     telemetry.bump("batch.louvain_graphs", len(idxs))
 
     bad_slots: List[int] = []
-    for b, i in enumerate(idxs):
-        if bool(bad_w[b]):
-            bad_slots.append(i)
-            continue
-        report = RunReport(faults=list(active_faults))
-        if promote:
-            report.warnings.append("precision:f32_accum_risk"
-                                   if not jax.config.jax_enable_x64
-                                   else "precision:promoted_f64")
-        levels = int(level[b])
-        sweeps_per_level = [int(s) for s in sw_h[b][:levels]]
-        res = LouvainResult(
-            labels=_unpack_labels(final_assign[b], graphs[i], sig.n_cap),
-            n_communities=int(n_final[b]),
-            levels=levels,
-            modularity=float(q_final[b]),
-            modularity_history=(
-                [float(x) for x in mod_h[b][:levels]]
-                if cfg.track_modularity else []),
-            sweeps_per_level=sweeps_per_level,
-            timer=timer,
-            n_comm_per_level=[int(x) for x in nc_h[b][:levels]],
-            delta_n_per_level=[
-                [int(x) for x in row[:s]]
-                for row, s in zip(dn_h[b][:levels], sweeps_per_level)],
-            cascade_stages=[(sig.n_cap, sig.m_cap)],
-        )
-        results[i] = _finalize_report(res, cfg, report)
+    with telemetry.span("repro.batch.unpack"):
+        for b, i in enumerate(idxs):
+            if bool(bad_w[b]):
+                bad_slots.append(i)
+                continue
+            report = RunReport(faults=list(active_faults))
+            if promote:
+                report.warnings.append("precision:f32_accum_risk"
+                                       if not jax.config.jax_enable_x64
+                                       else "precision:promoted_f64")
+            levels = int(level[b])
+            sweeps_per_level = [int(s) for s in sw_h[b][:levels]]
+            res = LouvainResult(
+                labels=_unpack_labels(final_assign[b], graphs[i], sig.n_cap),
+                n_communities=int(n_final[b]),
+                levels=levels,
+                modularity=float(q_final[b]),
+                modularity_history=(
+                    [float(x) for x in mod_h[b][:levels]]
+                    if cfg.track_modularity else []),
+                sweeps_per_level=sweeps_per_level,
+                timer=timer,
+                n_comm_per_level=[int(x) for x in nc_h[b][:levels]],
+                delta_n_per_level=[
+                    [int(x) for x in row[:s]]
+                    for row, s in zip(dn_h[b][:levels], sweeps_per_level)],
+                cascade_stages=[(sig.n_cap, sig.m_cap)],
+            )
+            results[i] = _finalize_report(res, cfg, report)
     return bad_slots
 
 
@@ -359,9 +388,9 @@ def _run_louvain_bucket(graphs, idxs, sig: CapacitySignature,
 
 
 @program_cache("batch.plp", maxsize=32)
-def _plp_batch_fn(sig: CapacitySignature, spec: EngineSpec):
-    """One compiled PLP batch program per capacity signature: the fused
-    phase loop (``engine.device_phase`` — singleton init, on-device
+def _plp_batch_fn(sig: CapacitySignature, slots: int, spec: EngineSpec):
+    """One compiled PLP batch program per capacity signature and slot
+    count: the fused phase loop (``engine.device_phase`` — singleton init, on-device
     convergence) lifted through ``jax.vmap``."""
 
     def run(g: Graph, seed):
@@ -404,22 +433,24 @@ def plp_batch(graphs: Sequence[Graph],
         buckets.setdefault((sig, g.sorted_by), []).append(i)
 
     for (sig, sorted_by), bucket_idxs in buckets.items():
-        if lane_schedule and len(bucket_idxs) > max_slots:
+        width = dispatch_width(sig, max_slots)
+        if lane_schedule and len(bucket_idxs) > width:
             telemetry.bump("batch.lane_scheduled_buckets")
             bucket_idxs = _schedule_lanes(graphs, bucket_idxs)
-        for idxs in _chunks(bucket_idxs, max_slots):
+        for idxs in _chunks(bucket_idxs, width):
             if deadline is not None and deadline.expired:
                 raise DeadlineError(
                     f"batch deadline ({deadline_s:.3f}s) expired with "
                     "bucket dispatches still pending")
             _run_plp_bucket(graphs, idxs, sig, sorted_by, cfg, faults,
-                            active_faults, results, deadline)
+                            active_faults, results, width, deadline)
     return results  # type: ignore[return-value]
 
 
 def _run_plp_bucket(graphs, idxs, sig: CapacitySignature, sorted_by,
                     cfg: PLPConfig, faults: frozenset, active_faults,
-                    results, deadline: Optional[resilience.Deadline] = None,
+                    results, slots: int,
+                    deadline: Optional[resilience.Deadline] = None,
                     ) -> None:
     timer = Timer()
     backend = _resolve_batch_backend(cfg.backend, sorted_by == "src")
@@ -428,31 +459,25 @@ def _run_plp_bucket(graphs, idxs, sig: CapacitySignature, sorted_by,
         spec = spec.replace(ell_width=sig.ell_width)
 
     with timer.phase("pack"):
-        padded = [packing.pad_graph(graphs[i], sig.n_cap, sig.m_cap)
-                  for i in idxs]
-        slots = pick_batch_slots(len(padded))
-        filler = packing.empty_slot(sig.n_cap, sig.m_cap)
-        if filler.sorted_by != sorted_by:
-            filler = dataclasses.replace(filler, sorted_by=sorted_by)
-        padded += [filler] * (slots - len(padded))
-        gb = packing.stack_graphs(padded)
-
-    fn = _plp_batch_fn(sig, spec)
+        gb = _pack(graphs, idxs, sig, sorted_by, slots)
+    fn = _plp_batch_fn(sig, slots, spec)
     with timer.phase("move"):
         labels, s, dn_hist, act_hist = _dispatch_guarded(
-            lambda: jax.device_get(fn(gb, jnp.uint32(cfg.seed))), deadline)
+            lambda: _dispatch(fn, gb, cfg.seed, len(idxs), jax.device_get),
+            deadline)
     telemetry.bump("batch.plp_dispatches")
     telemetry.bump("batch.plp_graphs", len(idxs))
 
-    for b, i in enumerate(idxs):
-        report = RunReport(faults=list(active_faults))
-        its = int(s[b])
-        if its >= cfg.max_iterations:
-            report.warnings.append("watchdog:max_iterations")
-        results[i] = PLPResult(
-            labels=np.asarray(labels[b][:graphs[i].n_max]),
-            iterations=its,
-            delta_n_history=[int(x) for x in dn_hist[b][:its]],
-            active_history=[int(x) for x in act_hist[b][:its]],
-            timer=timer,
-            run_report=report)
+    with telemetry.span("repro.batch.unpack"):
+        for b, i in enumerate(idxs):
+            report = RunReport(faults=list(active_faults))
+            its = int(s[b])
+            if its >= cfg.max_iterations:
+                report.warnings.append("watchdog:max_iterations")
+            results[i] = PLPResult(
+                labels=np.asarray(labels[b][:graphs[i].n_max]),
+                iterations=its,
+                delta_n_history=[int(x) for x in dn_hist[b][:its]],
+                active_history=[int(x) for x in act_hist[b][:its]],
+                timer=timer,
+                run_report=report)

@@ -18,10 +18,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+import jax
 import numpy as np
-import jax.numpy as jnp
 
-from repro.graph.structure import Graph, graph_from_arrays
+from repro.graph.structure import Graph, host_graph
 from repro.utils import telemetry
 from repro.utils.errors import InputValidationError
 
@@ -179,10 +179,12 @@ def from_numpy_edges(
     w: Optional[np.ndarray] = None,
     *,
     n: Optional[int] = None,
+    n_max: Optional[int] = None,
     m_max: Optional[int] = None,
     dedup: bool = True,
     sort_by: str = "src",
     validate: Optional[bool] = None,
+    device: bool = True,
 ) -> Graph:
     """Build a Graph from an undirected host edge list.
 
@@ -190,26 +192,26 @@ def from_numpy_edges(
     * input self-loops (u==v) are stored once with DOUBLED weight (paper §II-A:
       "loops are counted twice")
     * optional dedup merges parallel edges by weight summation
-    * ``validate`` runs ``validate_graph`` on the result (None defers to the
-      module-level ``DEFAULT_VALIDATE``, flipped on by the test conftest)
+    * pads on the host to the capacity ``(n_max, m_max)`` (defaults: ``n``
+      vertices, the edge count), so the device runs no program shaped by
+      the raw edge count; vertices ``[n, n_max)`` are padding
+    * ``validate`` runs ``validate_graph`` on the host arrays (None defers to
+      the module-level ``DEFAULT_VALIDATE``, flipped on by the test conftest)
+    * ``device`` moves each array to the device once; ``device=False``
+      keeps them on the host, for callers that stack graphs of one capacity
+      before one transfer (the serve engine, ``core.batch``)
     """
     with telemetry.span("repro.ingest"):
         with telemetry.span("repro.ingest.canonicalize"):
             src, dst, ww, n = _symmetrize_sorted(u, v, w, n, dedup, sort_by)
-        with telemetry.span("repro.ingest.to_device"):
-            g = graph_from_arrays(
-                jnp.asarray(src, dtype=jnp.int32),
-                jnp.asarray(dst, dtype=jnp.int32),
-                jnp.asarray(ww, dtype=jnp.float32),
-                n_max=n,
-                m_max=m_max,
-                n_valid=n,
-                sorted_by=sort_by,
-                validate=False,  # full validation below covers it
-            )
+            g = host_graph(src, dst, ww, n_max=n if n_max is None else n_max,
+                           m_max=m_max, n_valid=n, sorted_by=sort_by)
         if _resolve_validate(validate):
             with telemetry.span("repro.ingest.validate"):
                 validate_graph(g)
+        if device:
+            with telemetry.span("repro.ingest.to_device"):
+                g = jax.device_put(g)
         return g
 
 
@@ -258,21 +260,25 @@ def from_numpy_edges_robust(
     w: Optional[np.ndarray] = None,
     *,
     n: Optional[int] = None,
+    n_max: Optional[int] = None,
     m_max: Optional[int] = None,
     sort_by: str = "src",
     self_loops: str = "keep",
     bad_weights: str = "raise",
     bad_ids: str = "raise",
+    device: bool = True,
 ) -> Tuple[Graph, RepairReport]:
-    """Canonicalize → build → validate.  Clean input produces a Graph
-    bit-identical to ``from_numpy_edges(u, v, w, ...)``; repaired input is
-    described by the returned ``RepairReport``."""
+    """Canonicalize → build → validate (on the host, before any transfer).
+    Clean input produces a Graph bit-identical to ``from_numpy_edges(u, v,
+    w, ...)``; repaired input is described by the returned
+    ``RepairReport``.  ``n_max``, ``m_max`` and ``device`` are
+    ``from_numpy_edges``'."""
     u, v, w, n, report = canonicalize_edges(
         u, v, w, n=n, self_loops=self_loops, bad_weights=bad_weights,
         bad_ids=bad_ids)
     g = from_numpy_edges(
-        u, v, w, n=n, m_max=m_max, sort_by=sort_by, validate=False)
-    validate_graph(g)
+        u, v, w, n=n, n_max=n_max, m_max=m_max, sort_by=sort_by,
+        validate=True, device=device)
     return g, report
 
 

@@ -16,24 +16,30 @@ The batched many-graph engine (``core.batch``) relies on two facts about the
 
 ``pad_graph`` is the exact inverse direction of ``aggregation.shrink_graph``
 (grow instead of shrink); ``stack_graphs`` produces the batched container.
+All three work on the host, in numpy: a batch is padded and stacked there
+and moved to the device in one ``device_put``, so no program is traced or
+compiled per graph size or slot count.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
-import jax.numpy as jnp
+import jax
+import numpy as np
 
 from repro.graph.structure import Graph
 
 
 def pad_graph(g: Graph, n_cap: int, m_cap: int) -> Graph:
     """Re-pad ``g`` into LARGER static capacities (pure pad + sentinel
-    rewrite, on device).
+    rewrite, on the host: numpy leaves).
 
     Vertex ids are untouched (valid ids live in [0, n_valid) at any
     capacity); invalid src/dst entries are rewritten from the old ``n_max``
     sentinel to ``n_cap`` and new edge slots are appended fully masked, so
     every invariant (``sorted_by``, front-compaction, mask counts) survives.
+    A graph already at ``(n_cap, m_cap)`` — the serve engine ingests to its
+    bucket — is returned as it is.
     """
     n_cap, m_cap = int(n_cap), int(m_cap)
     if n_cap < g.n_max or m_cap < g.m_max:
@@ -42,16 +48,17 @@ def pad_graph(g: Graph, n_cap: int, m_cap: int) -> Graph:
             f"asked ({n_cap}, {m_cap})")
     if n_cap == g.n_max and m_cap == g.m_max:
         return g
-    sent = jnp.int32(n_cap)
+    g = jax.device_get(g)      # numpy leaves; a host graph stays as it is
     pad = m_cap - g.m_max
-    zeros_i = jnp.full((pad,), sent)
+    sent = np.full((pad,), n_cap, np.int32)
     return Graph(
-        src=jnp.concatenate([jnp.where(g.edge_mask, g.src, sent), zeros_i]),
-        dst=jnp.concatenate([jnp.where(g.edge_mask, g.dst, sent), zeros_i]),
-        w=jnp.concatenate([jnp.where(g.edge_mask, g.w, 0.0),
-                           jnp.zeros((pad,), jnp.float32)]),
-        edge_mask=jnp.concatenate([g.edge_mask,
-                                   jnp.zeros((pad,), bool)]),
+        src=np.concatenate([np.where(g.edge_mask, g.src, n_cap), sent]
+                           ).astype(np.int32),
+        dst=np.concatenate([np.where(g.edge_mask, g.dst, n_cap), sent]
+                           ).astype(np.int32),
+        w=np.concatenate([np.where(g.edge_mask, g.w, 0.0),
+                          np.zeros((pad,))]).astype(np.float32),
+        edge_mask=np.concatenate([g.edge_mask, np.zeros((pad,), bool)]),
         n_valid=g.n_valid,
         m_valid=g.m_valid,
         n_max=n_cap,
@@ -60,28 +67,28 @@ def pad_graph(g: Graph, n_cap: int, m_cap: int) -> Graph:
     )
 
 
-def empty_slot(n_cap: int, m_cap: int) -> Graph:
-    """A fully-masked (0 vertices, 0 edges) graph at the given capacities —
-    the batch-axis padding filler (DESIGN.md §Serving).  Runs through every
-    evaluator as a no-op lane: no valid vertex is ever active, every level
-    converges immediately (0 communities == 0 valid vertices), and the
-    modularity guard returns 0 for the zero-volume graph."""
-    sent = jnp.int32(n_cap)
+def empty_slot(n_cap: int, m_cap: int, sorted_by: str = "src") -> Graph:
+    """A fully-masked (0 vertices, 0 edges) graph at the given capacities,
+    on the host — the batch-axis filler (DESIGN.md §Serving).  Runs through
+    every evaluator as a no-op lane: no valid vertex is ever active, every
+    level converges immediately (0 communities == 0 valid vertices), and
+    the modularity guard returns 0 for the zero-volume graph."""
     return Graph(
-        src=jnp.full((m_cap,), sent),
-        dst=jnp.full((m_cap,), sent),
-        w=jnp.zeros((m_cap,), jnp.float32),
-        edge_mask=jnp.zeros((m_cap,), bool),
-        n_valid=jnp.int32(0),
-        m_valid=jnp.int32(0),
+        src=np.full((m_cap,), n_cap, np.int32),
+        dst=np.full((m_cap,), n_cap, np.int32),
+        w=np.zeros((m_cap,), np.float32),
+        edge_mask=np.zeros((m_cap,), bool),
+        n_valid=np.int32(0),
+        m_valid=np.int32(0),
         n_max=int(n_cap),
         m_max=int(m_cap),
-        sorted_by="src",
+        sorted_by=sorted_by,
     )
 
 
 def stack_graphs(graphs: Sequence[Graph]) -> Graph:
-    """Stack same-capacity Graphs along a new leading batch axis.
+    """Stack same-capacity Graphs along a new leading batch axis, on the
+    host (numpy), for ONE transfer of the batch.
 
     Returns a ``Graph`` whose DATA leaves carry a leading batch dimension
     (src/dst/w/edge_mask become ``(B, m_max)``, the valid counts ``(B,)``)
@@ -105,12 +112,12 @@ def stack_graphs(graphs: Sequence[Graph]) -> Graph:
                 f"sorted_by mismatch in batch: {g.sorted_by!r} vs "
                 f"{g0.sorted_by!r}")
     return Graph(
-        src=jnp.stack([g.src for g in graphs]),
-        dst=jnp.stack([g.dst for g in graphs]),
-        w=jnp.stack([g.w for g in graphs]),
-        edge_mask=jnp.stack([g.edge_mask for g in graphs]),
-        n_valid=jnp.stack([g.n_valid for g in graphs]),
-        m_valid=jnp.stack([g.m_valid for g in graphs]),
+        src=np.stack([np.asarray(g.src) for g in graphs]),
+        dst=np.stack([np.asarray(g.dst) for g in graphs]),
+        w=np.stack([np.asarray(g.w) for g in graphs]),
+        edge_mask=np.stack([np.asarray(g.edge_mask) for g in graphs]),
+        n_valid=np.stack([np.asarray(g.n_valid) for g in graphs]),
+        m_valid=np.stack([np.asarray(g.m_valid) for g in graphs]),
         n_max=g0.n_max,
         m_max=g0.m_max,
         sorted_by=g0.sorted_by,

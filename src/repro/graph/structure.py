@@ -117,6 +117,50 @@ class Graph:
         )
 
 
+def host_graph(
+    src,
+    dst,
+    w,
+    *,
+    n_max: int,
+    m_max: Optional[int] = None,
+    n_valid: Optional[int] = None,
+    sorted_by: Optional[str] = None,
+) -> Graph:
+    """Pad already-symmetrized directed edge arrays to capacity ON THE HOST:
+    a ``Graph`` whose leaves are numpy arrays (invalid entries hold the
+    ``n_max`` sentinel, zero weight and a clear ``edge_mask``).
+
+    Nothing runs on the device, so no program is traced or compiled for
+    the raw edge count; ``jax.device_put`` of the result moves each array
+    once (``graph_from_arrays``), and graphs of one capacity stack on the
+    host before a single transfer (``graph.packing``).
+    """
+    src, dst, w = np.asarray(src), np.asarray(dst), np.asarray(w)
+    m = src.shape[0]
+    # floor the edge capacity at 1: an edgeless graph keeps one fully-masked
+    # padding slot so every kernel's static-shape assumption (m_max >= 1,
+    # e.g. the GroupBy's (m-1,) run-start buffer) holds on degenerate input
+    m_max = m_max or max(m, 1)
+    if m_max < m:
+        raise ValueError(f"m_max={m_max} < m={m}")
+    if n_valid is not None and n_valid > n_max:
+        raise ValueError(f"n_valid={n_valid} > n_max={n_max}")
+    pad = m_max - m
+    sentinel = np.full((pad,), n_max, np.int32)
+    return Graph(
+        src=np.concatenate([src.astype(np.int32), sentinel]),
+        dst=np.concatenate([dst.astype(np.int32), sentinel]),
+        w=np.concatenate([w.astype(np.float32), np.zeros((pad,), np.float32)]),
+        edge_mask=np.arange(m_max) < m,
+        n_valid=np.int32(n_max if n_valid is None else n_valid),
+        m_valid=np.int32(m),
+        n_max=int(n_max),
+        m_max=int(m_max),
+        sorted_by=sorted_by,
+    )
+
+
 def graph_from_arrays(
     src: jax.Array,
     dst: jax.Array,
@@ -128,7 +172,8 @@ def graph_from_arrays(
     sorted_by: Optional[str] = None,
     validate: Optional[bool] = None,
 ) -> Graph:
-    """Wrap already-symmetrized directed edge arrays, padding to capacity.
+    """Wrap already-symmetrized directed edge arrays, padded to capacity on
+    the host (``host_graph``) and moved to the device one array each.
 
     ``validate`` runs the STRUCTURAL half of ``builders.validate_graph``
     (mask counts, id ranges, weight finiteness, sort invariant) on the
@@ -136,31 +181,9 @@ def graph_from_arrays(
     this function deliberately one-sided intermediates.  None defers to
     ``builders.DEFAULT_VALIDATE`` (flipped on by the test conftest).
     """
-    m = src.shape[0]
-    # floor the edge capacity at 1: an edgeless graph keeps one fully-masked
-    # padding slot so every kernel's static-shape assumption (m_max >= 1,
-    # e.g. the GroupBy's (m-1,) run-start buffer) holds on degenerate input
-    m_max = m_max or max(m, 1)
-    if m_max < m:
-        raise ValueError(f"m_max={m_max} < m={m}")
-    pad = m_max - m
-    sentinel = jnp.int32(n_max)
-    src = jnp.concatenate([src.astype(jnp.int32), jnp.full((pad,), sentinel)])
-    dst = jnp.concatenate([dst.astype(jnp.int32), jnp.full((pad,), sentinel)])
-    w = jnp.concatenate([w.astype(jnp.float32), jnp.zeros((pad,), jnp.float32)])
-    edge_mask = jnp.arange(m_max) < m
-    g = Graph(
-        src=src,
-        dst=dst,
-        w=w,
-        edge_mask=edge_mask,
-        n_valid=jnp.int32(n_max if n_valid is None else n_valid),
-        m_valid=jnp.int32(m),
-        n_max=int(n_max),
-        m_max=int(m_max),
-        sorted_by=sorted_by,
-    )
+    g = host_graph(src, dst, w, n_max=n_max, m_max=m_max, n_valid=n_valid,
+                   sorted_by=sorted_by)
     from repro.graph import builders  # late: builders imports this module
     if builders.DEFAULT_VALIDATE if validate is None else validate:
         builders.validate_graph(g, symmetry=False)
-    return g
+    return jax.device_put(g)

@@ -15,17 +15,19 @@ The contract under test, in order of importance:
      flow through a batch without poisoning their batch-mates, with the
      PR-7 per-graph RunReport discipline intact.
 """
+import jax
 import numpy as np
 import pytest
 
 from repro.core import progcache
-from repro.core.batch import louvain_batch, pick_batch_slots, plp_batch
+from repro.core.batch import MAX_SLOTS, louvain_batch, plp_batch
 from repro.core.louvain import LouvainConfig, louvain
 from repro.core.plp import PLPConfig, plp
 from repro.graph import packing
 from repro.graph.builders import from_numpy_edges
 from repro.graph.generators import rmat, sbm
 from repro.kernels.common import bucket_capacity, capacity_signature
+from repro.utils import telemetry
 
 E = np.zeros(0, np.int64)
 
@@ -76,11 +78,86 @@ def test_realistic_workloads_land_on_few_buckets():
     assert len(rsigs) <= 4, sorted(rsigs)
 
 
-def test_pick_batch_slots():
-    assert [pick_batch_slots(k) for k in (1, 2, 3, 5, 64, 65)] == \
-        [1, 2, 4, 8, 64, 128]
-    with pytest.raises(ValueError):
-        pick_batch_slots(0)
+class _Compiles:
+    """Counts jaxprs traced and executables compiled while the block runs
+    (JAX's monitoring events)."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+
+    def __enter__(self):
+        import jax
+
+        self.traces = self.compiles = 0
+        self._listener = self._on
+        jax.monitoring.register_event_duration_secs_listener(self._listener)
+        return self
+
+    def _on(self, event, duration, **_):
+        if event == self.TRACE:
+            self.traces += 1
+        elif event == self.COMPILE:
+            self.compiles += 1
+
+    def __exit__(self, *exc):
+        import jax
+
+        jax.monitoring.unregister_event_duration_listener(self._listener)
+
+
+def test_dispatches_of_one_to_eight_graphs_share_one_program():
+    """Every dispatch of a signature runs its ``dispatch_width`` lanes (8
+    here), so chunks of 1 to 8 graphs of one signature share ONE compiled
+    program: one miss in the program cache, and nothing traced or compiled
+    after the first."""
+    gs = []
+    for i in range(MAX_SLOTS):
+        u, v, _w, _t = sbm(40 + 7 * i, 4, p_in=0.3, p_out=0.02, seed=300 + i)
+        gs.append(from_numpy_edges(u, v, n=40 + 7 * i, n_max=128,
+                                   m_max=4096))
+    assert len({capacity_signature(g.n_max, g.m_max) for g in gs}) == 1
+    cfg = LouvainConfig(seed=11)    # a spec no other test compiles
+    misses0 = progcache.cache_stats()["batch.louvain"]["misses"]
+    first = louvain_batch(gs[:1], cfg)
+    assert progcache.cache_stats()["batch.louvain"]["misses"] == misses0 + 1
+    with _Compiles() as c:
+        out = [louvain_batch(gs[:k], cfg) for k in range(1, MAX_SLOTS + 1)]
+    assert (c.traces, c.compiles) == (0, 0)
+    assert progcache.cache_stats()["batch.louvain"]["misses"] == misses0 + 1
+    assert np.array_equal(out[0][0].labels, first[0].labels)
+    for k, res in enumerate(out, start=1):
+        assert len(res) == k
+        assert np.array_equal(res[-1].labels, louvain(gs[k - 1], cfg).labels)
+
+
+
+def test_wide_signatures_run_fewer_lanes():
+    """``dispatch_width``: ``max_slots`` lanes while a dispatch carries at
+    most ``LANE_ENTRIES`` edge entries, fewer for wider signatures, at
+    least one; a bucket of a wide signature is chunked at that width and
+    its filler lanes are counted."""
+    from repro.core.batch import LANE_ENTRIES, dispatch_width
+
+    widths = {m: dispatch_width(capacity_signature(256, m))
+              for m in (256, 8192, 16384, 32768, 1 << 20)}
+    assert widths == {256: MAX_SLOTS, 8192: 8, 16384: 4, 32768: 2,
+                      1 << 20: 1}
+    for m, w in widths.items():
+        assert w == 1 or w * capacity_signature(256, m).m_cap <= LANE_ENTRIES
+    assert dispatch_width(capacity_signature(64, 256), max_slots=3) == 3
+    gs = []
+    for i in range(5):
+        u, v, _w, _t = sbm(60 + 9 * i, 4, p_in=0.3, p_out=0.02, seed=700 + i)
+        gs.append(from_numpy_edges(u, v, n=60 + 9 * i, n_max=256,
+                                   m_max=16384))
+    c0 = {k: telemetry.get(k) for k in ("batch.lanes", "batch.empty_lanes",
+                                        "batch.louvain_dispatches")}
+    out = louvain_batch(gs)
+    grew = {k: telemetry.get(k) - c0[k] for k in c0}
+    assert grew == {"batch.lanes": 8, "batch.empty_lanes": 3,
+                    "batch.louvain_dispatches": 2}
+    for g, res in zip(gs, out):
+        assert np.array_equal(res.labels, louvain(g).labels)
 
 
 # ------------------------------------------------------------------- packing
@@ -99,6 +176,83 @@ def test_pad_graph_grow_only_and_parity():
     assert r0.modularity == r1.modularity
     with pytest.raises(ValueError):
         packing.pad_graph(p, 128, 2048)
+
+
+def _device_padded(u, v, w, n, n_cap, m_cap):
+    """Ingest as it was before padding moved to the host: the canonical
+    arrays moved to the device and padded there to the edge count
+    (``graph_from_arrays``), then re-padded to the bucket (``pad_graph``),
+    with eager ``jnp`` ops."""
+    import jax.numpy as jnp
+
+    from repro.graph.builders import _symmetrize_sorted
+
+    src, dst, ww, n = _symmetrize_sorted(u, v, w, n, True, "src")
+    m = src.shape[0]
+    m_max = max(m, 1)
+    sent = jnp.int32(n)
+    src = jnp.concatenate([jnp.asarray(src, jnp.int32),
+                           jnp.full((m_max - m,), sent)])
+    dst = jnp.concatenate([jnp.asarray(dst, jnp.int32),
+                           jnp.full((m_max - m,), sent)])
+    ww = jnp.concatenate([jnp.asarray(ww, jnp.float32),
+                          jnp.zeros((m_max - m,), jnp.float32)])
+    mask = jnp.arange(m_max) < m
+    cap = jnp.int32(n_cap)
+    pad = m_cap - m_max
+    return dict(
+        src=jnp.concatenate([jnp.where(mask, src, cap),
+                             jnp.full((pad,), cap)]),
+        dst=jnp.concatenate([jnp.where(mask, dst, cap),
+                             jnp.full((pad,), cap)]),
+        w=jnp.concatenate([jnp.where(mask, ww, 0.0),
+                           jnp.zeros((pad,), jnp.float32)]),
+        edge_mask=jnp.concatenate([mask, jnp.zeros((pad,), bool)]),
+        n_valid=jnp.int32(n), m_valid=jnp.int32(m), n_max=n_cap,
+        m_max=m_cap, sorted_by="src")
+
+
+@pytest.mark.parametrize("case", ["sbm", "loops_and_duplicates", "weighted",
+                                  "isolates", "no_edges"])
+def test_host_padded_ingest_equals_device_padded_graph(case):
+    """``from_numpy_edges`` padded on the host straight to a bucket gives,
+    field by field (values, dtypes, sentinels, static meta), the graph
+    that device-side padding gave; the device copy moves each array once
+    and runs no program."""
+    rng = np.random.default_rng(7)
+    n, w = 50, None
+    if case == "sbm":
+        u, v, _w, _t = sbm(n, 4, p_in=0.3, p_out=0.02, seed=400)
+    elif case == "loops_and_duplicates":
+        u = np.array([0, 1, 1, 3, 3, 0, 7, 7])
+        v = np.array([1, 0, 1, 3, 4, 1, 2, 2])
+    elif case == "weighted":
+        u = rng.integers(0, n, 300)
+        v = rng.integers(0, n, 300)
+        w = rng.random(300) * 3.0
+    elif case == "isolates":
+        u, v = np.array([0, 2]), np.array([1, 3])
+    else:
+        u = v = E
+    sig = capacity_signature(n, max(2 * len(u), 1))
+    want = _device_padded(u, v, w, n, sig.n_cap, sig.m_cap)
+    with _Compiles() as c:
+        for device in (False, True):
+            g = from_numpy_edges(u, v, w, n=n, n_max=sig.n_cap,
+                                 m_max=sig.m_cap, device=device)
+            for f, x in want.items():
+                got = getattr(g, f)
+                if isinstance(x, (int, str)):
+                    assert got == x, f
+                    continue
+                assert np.asarray(got).dtype == x.dtype, f
+                assert np.asarray(got).shape == x.shape, f
+                assert np.array_equal(np.asarray(got), np.asarray(x)), f
+    assert c.compiles == 0
+    assert isinstance(g.src, jax.Array)
+    # without a capacity: the unpadded graph it always was
+    plain = from_numpy_edges(u, v, w, n=n)
+    assert (plain.n_max, plain.m_max) == (n, max(int(plain.m_valid), 1))
 
 
 def test_stack_graphs_validates():
@@ -159,9 +313,9 @@ def test_batch_padding_slots_do_not_change_results():
     clustered alone, in a ragged batch, and in a full batch gets identical
     labels (vmap-lane independence)."""
     gs = _sbm_graphs([70, 70, 70, 70, 70], seed0=20)
-    alone = louvain_batch(gs[:1])[0]
-    ragged = louvain_batch(gs[:3])[0]      # 3 → 4 slots, 1 filler
-    full = louvain_batch(gs)[0]            # 5 → 8 slots, 3 fillers
+    alone = louvain_batch(gs[:1])[0]       # 1 graph, 7 filler lanes
+    ragged = louvain_batch(gs[:3])[0]      # 3 graphs, 5 filler lanes
+    full = louvain_batch(gs)[0]            # 5 graphs, 3 filler lanes
     assert np.array_equal(alone.labels, ragged.labels)
     assert np.array_equal(alone.labels, full.labels)
     assert alone.modularity == ragged.modularity == full.modularity
@@ -342,3 +496,65 @@ def test_serve_engine_end_to_end():
     r9 = eng.flush()[0]
     assert r9.ok
     assert eng.stats()["programs"]["batch.louvain"]["misses"] == misses0
+
+
+def test_a_symmetric_request_lands_in_its_one_sided_bucket():
+    """Requests are bucketed on their canonical sizes: one that lists both
+    directions of every edge, and some edges twice, lands in the bucket of
+    its one-sided form (its raw size, ``2·len(u)``, is a bucket up) and is
+    served in the same dispatch."""
+    from launch.community_serve import (CommunityRequest,
+                                        CommunityServeEngine, ingest_request)
+
+    u, v, _w, _t = sbm(90, 4, p_in=0.3, p_out=0.02, seed=610)
+    one = CommunityRequest(request_id="one", u=u, v=v, n=90)
+    both = CommunityRequest(request_id="both", u=np.concatenate([u, v, u[:9]]),
+                            v=np.concatenate([v, u, v[:9]]), n=90)
+    g1, _ = ingest_request(one)
+    g2, _ = ingest_request(both)
+    sig = capacity_signature(g1.n_max, g1.m_max)
+    assert (g2.n_max, g2.m_max) == (g1.n_max, g1.m_max) == (sig.n_cap,
+                                                            sig.m_cap)
+    assert int(g2.m_valid) == int(g1.m_valid)
+    assert capacity_signature(90, 2 * len(both.u)).m_cap > sig.m_cap
+    eng = CommunityServeEngine()
+    eng.submit(one)
+    eng.submit(both)
+    d0 = telemetry.get("batch.louvain_dispatches")
+    r1, r2 = eng.flush()
+    assert telemetry.get("batch.louvain_dispatches") - d0 == 1
+    assert r1.ok and r2.ok and r1.signature == r2.signature == tuple(sig)
+    assert r2.labels.shape == (90,)
+
+
+def test_second_edge_count_in_a_bucket_compiles_nothing():
+    """Requests are ingested on the host straight to their bucket, so once
+    a signature's batch program exists a request of another edge count in
+    that bucket traces and compiles nothing, from submit to response."""
+    from launch.community_serve import (CommunityRequest,
+                                        CommunityServeEngine, ingest_request)
+
+    def request_signature(req):
+        g, _ = ingest_request(req)
+        return capacity_signature(g.n_max, g.m_max)
+
+    eng = CommunityServeEngine()
+    reqs = []
+    for i, (n, p_in) in enumerate([(70, 0.3), (90, 0.25), (100, 0.2)]):
+        u, v, _w, _t = sbm(n, 4, p_in=p_in, p_out=0.02, seed=500 + i)
+        reqs.append(CommunityRequest(request_id=f"e{i}", u=u, v=v, n=n))
+    sigs = {request_signature(r) for r in reqs}
+    assert len(sigs) == 1 and len({len(r.u) for r in reqs}) == 3
+    eng.submit(reqs[0])
+    first = eng.flush()[0]
+    with _Compiles() as c:
+        for r in reqs[1:]:
+            eng.submit(r)
+        rest = eng.flush()
+    assert (c.traces, c.compiles) == (0, 0)
+    for r, resp in zip(reqs, [first] + rest):
+        assert resp.ok and resp.labels.shape == (r.n,)
+        assert resp.signature == tuple(request_signature(r))
+        single = louvain(from_numpy_edges(r.u, r.v, n=r.n))
+        assert np.array_equal(resp.labels, single.labels)
+        assert resp.result.modularity == single.modularity

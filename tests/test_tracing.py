@@ -108,3 +108,30 @@ def test_ingest_emits_host_spans(tmp_path):
     for child in ("repro.ingest.canonicalize", "repro.ingest.to_device",
                   "repro.ingest.validate"):
         assert _nested(spans, child, "repro.ingest"), child
+
+
+def test_serve_emits_host_spans(tmp_path):
+    """A served tick names each host step: submit (with the ingest inside
+    it), flush, and the batch engine's pack, dispatch, readback and
+    unpack inside the flush."""
+    from launch.community_serve import CommunityRequest, CommunityServeEngine
+
+    reqs = []
+    for i, n in enumerate((60, 70)):
+        u, v, _w, _t = sbm(n, 4, p_in=0.3, p_out=0.03, seed=40 + i)
+        reqs.append(CommunityRequest(request_id=f"t{i}", u=u, v=v, n=n))
+    eng = CommunityServeEngine()
+
+    def tick():
+        for r in reqs:
+            eng.submit(r)
+        assert all(resp.ok for resp in eng.flush())
+
+    tick()                              # compile outside the trace
+    spans = _spans(tmp_path, tick)
+    assert len(spans["repro.serve.submit"]) == len(reqs)
+    assert len(spans["repro.serve.flush"]) == 1
+    assert _nested(spans, "repro.ingest", "repro.serve.submit")
+    for child in ("repro.batch.pack", "repro.batch.dispatch",
+                  "repro.batch.readback", "repro.batch.unpack"):
+        assert _nested(spans, child, "repro.serve.flush"), child
